@@ -372,24 +372,28 @@ class AdmissionController:
                 if not backlogged:
                     break
                 t = min(backlogged, key=lambda t: (t.vtime, t.name))
-                req, _t_in = t.queue.popleft()
+                req, t_in = t.queue.popleft()
                 cost = max(1, len(req.prompt_tokens))
                 t.vtime += cost / t.cfg.weight
                 t.released += 1
                 t.released_tokens += cost
                 self.total_released += 1
                 self._depth -= 1
-                self._note_release_locked(now)
+                self._note_release_locked(now, t_in)
                 ready.append(req)
         return ready, expired
 
-    def _note_release_locked(self, now: float) -> None:
-        if self._last_release is not None:
-            gap = max(1e-6, now - self._last_release)
-            inst = 1.0 / gap
-            alpha = 0.1
-            self._release_rate = ((1 - alpha) * self._release_rate
-                                  + alpha * inst)
+    def _note_release_locked(self, now: float, t_in: float) -> None:
+        # the rate measures the queue while it is backlogged: each gap runs
+        # from the later of the previous release and this request's
+        # arrival, so idle time between requests does not read as slow
+        # service (a burst right after one cold request is not shed)
+        start = (t_in if self._last_release is None
+                 else max(self._last_release, t_in))
+        gap = max(1e-6, now - start)
+        inst = 1.0 / gap
+        alpha = 0.1
+        self._release_rate = (1 - alpha) * self._release_rate + alpha * inst
         self._last_release = now
 
     def drop(self, request_id: int) -> Optional[Request]:

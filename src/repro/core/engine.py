@@ -270,12 +270,18 @@ class InferenceEngine:
         spec_draft_config: Optional[Any] = None,  # name | ModelConfig
         spec_draft_params: Optional[Any] = None,  # None = seeded init
         spec_ngram_max: int = 3,         # longest lookup n-gram
+        device: Optional[Any] = None,    # jax.Device for params/KV/state
     ):
         self.cfg = cfg
         self.model = build_model(cfg)
         self.seed = seed
+        # every compiled program follows its committed inputs, so pinning
+        # the params, the KV pool and the decode state to ``device`` runs
+        # this engine on that chip (router replicas: one chip each)
+        self.device = device
         key = jax.random.PRNGKey(seed)
-        self.params = params if params is not None else self.model.init(key)
+        self.params = self._on_device(
+            params if params is not None else self.model.init(key))
         self.tokenizer = tokenizer or ByteTokenizer()
         # engine-level sampling knobs are *per-request fallbacks*: a request
         # whose SamplingParams leaves top_p/top_k/min_p as None inherits
@@ -348,6 +354,7 @@ class InferenceEngine:
             assert kv_dtype == "fp", "int8 KV requires kv_layout='paged'"
             self.pool = SlotKVPool(cfg, max_batch, cache_len,
                                    ctx_len=self.ctx_len)
+        self.pool.cache = self._on_device(self.pool.cache)
         # COW page leases pinned by in-flight prefill jobs (request_id ->
         # page ids incref'd at prefix-cache lookup); ownership transfers to
         # the slot at commit, or is released on job failure/termination
@@ -394,8 +401,8 @@ class InferenceEngine:
         # requests derive their base key from the seed alone, unseeded ones
         # draw from this engine-owned chain at add_request (deterministic
         # for a fixed engine seed + submission order).
-        self.state = init_decode_state(max_batch, self.ctx_len,
-                                       max_stop_tokens, spec_k=self.spec_k)
+        self.state = self._on_device(init_decode_state(
+            max_batch, self.ctx_len, max_stop_tokens, spec_k=self.spec_k))
         self._request_rng = jax.random.PRNGKey(seed + 1)
         self._streamers: Dict[int, TokenStreamDecoder] = {}
         # per-request stop-sequence checkers (only for requests that set
@@ -479,6 +486,11 @@ class InferenceEngine:
                     cache_len=cache_len, seed=seed)
             else:
                 self._draft_source = NGramDraftSource(max_n=spec_ngram_max)
+
+    def _on_device(self, tree):
+        """``tree`` committed to this engine's device (as is without one)."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
 
     # ------------------------------------------------------------------ #
     # compiled steps
@@ -2034,9 +2046,9 @@ class InferenceEngine:
                   len(self._live_slots))
         # fresh decode state first: the failure paths below touch it
         # (_deactivate_slot), and the donated one may already be invalid
-        self.state = init_decode_state(self.pool.max_batch, self.ctx_len,
-                                       self.max_stop_tokens,
-                                       spec_k=self.spec_k)
+        self.state = self._on_device(init_decode_state(
+            self.pool.max_batch, self.ctx_len, self.max_stop_tokens,
+            spec_k=self.spec_k))
         if isinstance(self._draft_source, DraftModelSource):
             # the draft pool/state may have been donated into the failed
             # round as well — rebuild both; slots re-prime at re-admission
@@ -2081,6 +2093,7 @@ class InferenceEngine:
                                self.pool.cache_len, ctx_len=self.ctx_len)
         fresh._free = list(self.pool._free)
         fresh._used = set(self.pool._used)
+        fresh.cache = self._on_device(fresh.cache)
         self.pool = fresh
 
     def drain_snapshot(self) -> List[StreamEvent]:
@@ -2224,7 +2237,8 @@ class InferenceEngine:
         if rec.get("stopchk") is not None:
             self._stopchk[rid] = rec["stopchk"]
         if rec.get("cache") is not None:
-            self._evicted[rid] = {"cache": rec["cache"],
+            # the snapshot may live on the exporting replica's chip
+            self._evicted[rid] = {"cache": self._on_device(rec["cache"]),
                                   "ctx_valid": rec.get("ctx_valid")}
         elif req.output_tokens:
             # mid-generation record without a snapshot: resume by

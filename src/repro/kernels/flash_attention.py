@@ -11,7 +11,13 @@ Supports causal masking, sliding windows, GQA (kv-head indexing in the
 BlockSpec index_map — no materialised head repetition), and chunked prefill
 via ``q_offset``.
 
-Validated against ``ref.flash_attention_ref`` with interpret=True (CPU).
+TPU tiling: a block's last two dimensions must be multiples of (8, 128) or
+span the whole array, so the wrapper moves the head axis ahead of the
+sequence axis (``[B, H, S, D]``) and every block is ``(1, 1, rows, D)``.
+
+Validated against ``ref.flash_attention_ref`` in interpret mode on the CPU;
+compiles for TPU v5e (``tests/test_tpu_compile.py``) and is checked against
+the reference on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -40,7 +46,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     # block-level early-out: skip fully-masked KV blocks (upper triangle /
     # outside the sliding window / padding)
-    block_live = kpos[0, 0] < skv
+    block_live = ik * bk < skv
     if causal:
         block_live &= (ik * bk) <= (q_offset + iq * bq + bq - 1)
     if window > 0:
@@ -48,9 +54,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # [bq, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # [bk, d]
+        q = q_ref[0, 0].astype(jnp.float32)                # [bq, d]
+        k = k_ref[0, 0].astype(jnp.float32)                # [bk, d]
+        v = v_ref[0, 0].astype(jnp.float32)                # [bk, d]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         mask = kpos < skv
@@ -73,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -100,10 +106,14 @@ def flash_attention_pallas(
     bk = min(block_k, max(skv, 8))
     sq_p = -(-sq // bq) * bq
     skv_p = -(-skv // bk) * bk
+    # head-major: blocks (1, 1, rows, d) meet the TPU tiling rule
+    q = jnp.swapaxes(q, 1, 2)
+    k = jnp.swapaxes(k, 1, 2)
+    v = jnp.swapaxes(v, 1, 2)
     if sq_p != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     if skv_p != skv:
-        pad = ((0, 0), (0, skv_p - skv), (0, 0), (0, 0))
+        pad = ((0, 0), (0, 0), (0, skv_p - skv), (0, 0))
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
     nq, nk = sq_p // bq, skv_p // bk
@@ -116,20 +126,21 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h_, iq, ik, rep=rep: (b_, ik, h_ // rep, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda b_, h_, iq, ik, rep=rep: (b_, ik, h_ // rep, 0)),
+            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, iq, ik, rep=rep: (b_, h_ // rep, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, iq, ik, rep=rep: (b_, h_ // rep, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, d),
-                               lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, d),
+                               lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),      # acc
             pltpu.VMEM((bq, 128), jnp.float32),    # running max m
             pltpu.VMEM((bq, 128), jnp.float32),    # running sum l
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return jnp.swapaxes(out[:, :, :sq], 1, 2)

@@ -1,6 +1,11 @@
 """Production entry points for every kernel: dispatch Pallas-on-TPU vs
 chunked-jnp-on-CPU, with identical semantics (tests pin all paths to ref.py).
 
+On TPU the Pallas kernels are compiled, never interpreted, and there is no
+fallback: a kernel that fails to lower raises.  Two calls take the jnp path
+on TPU by design: prefill that resumes from cached tokens (per-row
+``q_positions``) and cross-attention prefill (``kv_valid``).
+
 The chunked jnp paths are not toys: they are the implementations the dry-run
 lowers (this container targets TPU but runs on CPU), so they are written
 flash-style — O(S) memory via lax.scan over KV chunks — to keep
@@ -11,7 +16,7 @@ flash-style — O(S) memory via lax.scan over KV chunks — to keep
     masked upper-triangle blocks too).
   * ``schedule='causal'`` — per-q-chunk KV extents (python loop over q chunks,
     static slice bounds): skips fully-masked blocks, ~2x fewer attention FLOPs
-    at long context.  This is a §Perf hillclimb lever; see EXPERIMENTS.md.
+    at long context.
 """
 from __future__ import annotations
 

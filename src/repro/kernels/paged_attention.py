@@ -5,21 +5,25 @@ global page arena ``[N, page_size, Hkv, D]`` shared by every sequence, and a
 per-slot page table ``[B, P]`` maps each sequence's logical cache blocks to
 arena pages.  The kernel rides the page indirection on the BlockSpec index
 map: the page table and query positions arrive as scalar-prefetch operands
-(``pltpu.PrefetchScalarGridSpec``), so grid step ``(b, h, ip)`` DMA's arena
+(``pltpu.PrefetchScalarGridSpec``), so grid step ``(b, ip)`` DMA's arena
 page ``page_table[b, ip]`` directly into VMEM — the gather costs nothing
 over a contiguous layout, because block fetches were always index-mapped.
 
-Grid is ``(batch, kv_heads, pages)`` with the page axis innermost and
-sequential; flash (m, l, acc) statistics carry across pages in VMEM scratch
-exactly as in the dense kernel.  Cell validity is computed in-kernel from
-the query position (ring semantics: a fully wrapped cache attends to every
-cell), so no [B, S] mask array is materialised.
+Grid is ``(batch, pages)`` with the page axis innermost and sequential;
+flash (m, l, acc) statistics carry across pages in VMEM scratch exactly as
+in the dense kernel.  Each step takes every KV head of its page (the arena
+is viewed as ``[N, ps, Hkv*D]``, heads side by side on the lane axis), so
+the block's last two dimensions meet the TPU tiling rule.  Cell validity is
+computed in-kernel from the query position (ring semantics: a fully wrapped
+cache attends to every cell), so no [B, S] mask array is materialised.
 
-Int8 arenas add per-(position, kv-head) scale operands; pages are
-dequantised in-register after the VMEM load (bandwidth is spent on int8
-bytes, the matmul runs in f32).
+Int8 arenas add per-(position, kv-head) scale operands ``[N, ps, Hkv]``
+(block ``(1, ps, Hkv)``); pages are dequantised in-register after the VMEM
+load (bandwidth is spent on int8 bytes, the matmul runs in f32).
 
-Validated against ``ref.paged_attention_ref`` with interpret=True (CPU).
+Validated against ``ref.paged_attention_ref`` in interpret mode on the CPU;
+compiles for TPU v5e (``tests/test_tpu_compile.py``) and is checked against
+the reference on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -34,13 +38,13 @@ from repro.kernels.ref import NEG_INF
 
 
 def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
-                  num_pages, ps, g, int8):
+                  num_pages, ps, hkv, d, int8):
     if int8:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     b_ = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -48,38 +52,40 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :].astype(jnp.float32)              # [G, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # [ps, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)              # [ps, D]
-    if int8:
-        k = k * ks_ref[0, :, 0][:, None]
-        v = v * vs_ref[0, :, 0][:, None]
-
     # ring validity from the query position (2D iota: TPU requirement)
     pos = pos_ref[b_]
     total = num_pages * ps
     idx = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    live = (idx[0] <= pos) | (pos >= total)                # [ps] bool
+    live = (idx <= pos) | (pos >= total)                   # [1, ps] bool
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(live[None, :], s, NEG_INF)               # [G, ps]
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32)                # [G, D]
+        k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)   # [ps, D]
+        v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)   # [ps, D]
+        if int8:
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
 
-    m_prev = m_ref[:, 0]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    p = jnp.where(live[None, :], p, 0.0)
-    l_cur = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_cur[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_cur[:, None], l_ref.shape)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, NEG_INF)                    # [G, ps]
+
+        m_prev = m_ref[h][:, :1]                           # [G, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        p = jnp.where(live, p, 0.0)
+        l_cur = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[h] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_cur, l_ref.shape[1:])
 
     @pl.when(ip == num_pages - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        for h in range(hkv):
+            l = jnp.maximum(l_ref[h][:, :1], 1e-30)
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -95,7 +101,7 @@ def paged_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    n, ps, hkv = k_pages.shape[:3]
     p = page_table.shape[1]
     g = h // hkv
     int8 = k_scale is not None
@@ -103,43 +109,42 @@ def paged_attention_pallas(
 
     # index maps see (grid idxs..., *scalar_prefetch_refs); the page hop is
     # pt[b_, ip] — the whole point of the kernel
-    def kv_map(b_, h_, ip, pt, pos):
-        return (pt[b_, ip], 0, h_, 0)
+    def kv_map(b_, ip, pt, pos):
+        return (pt[b_, ip], 0, 0)
 
-    def sc_map(b_, h_, ip, pt, pos):
-        return (pt[b_, ip], 0, h_)
-
-    def q_map(b_, h_, ip, pt, pos):
-        return (b_, h_, 0, 0)
+    def q_map(b_, ip, pt, pos):
+        return (b_, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_map),
-        pl.BlockSpec((1, ps, 1, d), kv_map),
-        pl.BlockSpec((1, ps, 1, d), kv_map),
+        pl.BlockSpec((1, hkv, g, d), q_map),
+        pl.BlockSpec((1, ps, hkv * d), kv_map),
+        pl.BlockSpec((1, ps, hkv * d), kv_map),
     ]
-    operands = [qg, k_pages, v_pages]
+    operands = [qg, k_pages.reshape(n, ps, hkv * d),
+                v_pages.reshape(n, ps, hkv * d)]
     if int8:
-        in_specs += [pl.BlockSpec((1, ps, 1), sc_map),
-                     pl.BlockSpec((1, ps, 1), sc_map)]
+        in_specs += [pl.BlockSpec((1, ps, hkv), kv_map),
+                     pl.BlockSpec((1, ps, hkv), kv_map)]
         operands += [k_scale, v_scale]
 
     kernel = functools.partial(_paged_kernel, scale=1.0 / (d ** 0.5),
-                               num_pages=p, ps=ps, g=g, int8=int8)
+                               num_pages=p, ps=ps, hkv=hkv, d=d, int8=int8)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, p),
+        grid=(b, p),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), q_map),
+        out_specs=pl.BlockSpec((1, hkv, g, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(page_table.astype(jnp.int32), positions.astype(jnp.int32), *operands)
     return out.reshape(b, h, d)

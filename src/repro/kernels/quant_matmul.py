@@ -6,7 +6,9 @@ and dequantised in VMEM right before the MXU matmul.  Grid is (M, N, K)
 blocks with the K dimension innermost (sequential), accumulating in an f32
 VMEM scratch tile; scales are applied once on the final K block.
 
-Validated against ``ref.quant_matmul_ref`` with interpret=True (CPU).
+Validated against ``ref.quant_matmul_ref`` in interpret mode on the CPU;
+compiles for TPU v5e (``tests/test_tpu_compile.py``) and is checked against
+the reference on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -75,6 +77,7 @@ def quant_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_p, n_p), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="quant_matmul",
         interpret=interpret,
     )(x, w_q, scales2d)
     return out[:m, :n]

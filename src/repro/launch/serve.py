@@ -3,16 +3,23 @@ batching engine.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b-toy \\
       --port 8177 --max-batch 8
+
+``build_parser``, ``load_configs`` and ``build_replica`` are the pieces
+``main`` assembles; ``chip_smoke.py`` builds its server from the same ones.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
 import threading
-import time
+from pathlib import Path
+from typing import Optional, Tuple
 
-from repro.configs import get_config
+import jax
+
+from repro.configs import ModelConfig, get_config
 from repro.core.admission import AdmissionController, TenantConfig
 from repro.core.engine import InferenceEngine
 from repro.core.faults import FaultInjector, parse_fault_rates
@@ -36,7 +43,26 @@ def parse_tenant_spec(spec: str) -> tuple:
     return name.strip(), TenantConfig(weight=weight, rps=rps, tps=tps)
 
 
-def main() -> None:
+# <checkout>/.jax_cache: a fixed path (never a temp name, pid or time), so a
+# second run finds what the first one compiled
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads it
+    itself); otherwise the cache lives in the checkout's ``.jax_cache``.
+    Called by entry points only — never on import — so library users and
+    the tests stay uncached.  Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b-toy")
     ap.add_argument("--smoke", action="store_true")
@@ -190,8 +216,12 @@ def main() -> None:
                     help="registered model config name for the draft "
                          "model (--spec-mode draft); must share the "
                          "target's vocab and be text-only attention")
-    args = ap.parse_args()
+    return ap
 
+
+def load_configs(args: argparse.Namespace
+                 ) -> Tuple[ModelConfig, Optional[object]]:
+    """(model config, draft config name or config) for parsed ``args``."""
     cfg = get_config(args.arch)
     spec_draft = args.spec_draft_config
     if args.smoke:
@@ -200,6 +230,64 @@ def main() -> None:
             # shrink the draft alongside the target, or its full-size vocab
             # can never match the reduced target's
             spec_draft = get_config(spec_draft).reduced()
+    return cfg, spec_draft
+
+
+def build_replica(args: argparse.Namespace, cfg: ModelConfig, *,
+                  index: int = 0, spec_draft: Optional[object] = None,
+                  faults: Optional[FaultInjector] = None) -> EngineClient:
+    """One engine + admission + lifecycle client.  Replicas share the seed,
+    so they are weight-identical — the property drain/handoff bit-identity
+    rests on.  Replica ``index`` keeps its params, KV cache and decode
+    state on ``jax.devices()[index]`` (modulo the device count), so N
+    replicas on an N-chip host each own a chip."""
+    devices = jax.devices()
+    engine = InferenceEngine(
+        cfg, max_batch=args.max_batch, cache_len=args.cache_len,
+        seed=args.seed, enable_prefix_cache=not args.no_prefix_cache,
+        enable_content_cache=not args.no_content_cache,
+        cache_vision_embeddings=not args.no_vision_embed_cache,
+        cache_vision_kv=not args.no_vision_kv_cache,
+        content_cache_bytes=(None if args.content_cache_mb is None
+                             else args.content_cache_mb * 1024 * 1024),
+        vision_work_iters=args.vision_work_iters,
+        encode_wave=args.encode_wave,
+        max_decode_block=args.max_decode_block,
+        top_p=args.top_p, top_k=args.top_k, min_p=args.min_p,
+        prefill_chunk=args.prefill_chunk,
+        max_prefill_buckets=args.max_prefill_buckets,
+        sched_policy=args.sched_policy,
+        preemption=args.preemption,
+        max_preemptions=args.max_preemptions,
+        speculative_fill=not args.no_spec_fill,
+        aging_s=args.aging_s,
+        faults=faults,
+        kv_layout=args.kv_layout,
+        kv_page_size=args.kv_page_size,
+        kv_num_pages=args.kv_num_pages,
+        kv_dtype=args.kv_dtype,
+        spec_mode=args.spec_mode,
+        spec_k=args.spec_k,
+        spec_draft_config=spec_draft,
+        device=devices[index % len(devices)])
+    admission = None
+    if not args.no_admission:
+        admission = AdmissionController(
+            tenants=dict(parse_tenant_spec(s) for s in args.tenant),
+            max_queue_depth=args.max_queue_depth,
+            queue_timeout_s=args.queue_timeout,
+            shed_queue_depth=args.shed_queue_depth,
+            shed_wait_s=args.shed_wait)
+    return EngineClient(
+        engine, admission=admission,
+        watchdog_timeout_s=(args.watchdog_timeout
+                            if args.watchdog_timeout > 0 else None))
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    cfg, spec_draft = load_configs(args)
     print(f"loading {cfg.name} ({cfg.param_count()/1e6:.1f}M params)...")
     faults = None
     rates = parse_fault_rates(args.fault_rate)
@@ -209,57 +297,16 @@ def main() -> None:
     if args.replicas < 1:
         raise SystemExit("--replicas must be >= 1")
 
-    def build_replica() -> EngineClient:
-        """One engine + admission + lifecycle client.  Replicas share the
-        seed, so they are weight-identical — the property drain/handoff
-        bit-identity rests on."""
-        engine = InferenceEngine(
-            cfg, max_batch=args.max_batch, cache_len=args.cache_len,
-            seed=args.seed, enable_prefix_cache=not args.no_prefix_cache,
-            enable_content_cache=not args.no_content_cache,
-            cache_vision_embeddings=not args.no_vision_embed_cache,
-            cache_vision_kv=not args.no_vision_kv_cache,
-            content_cache_bytes=(None if args.content_cache_mb is None
-                                 else args.content_cache_mb * 1024 * 1024),
-            vision_work_iters=args.vision_work_iters,
-            encode_wave=args.encode_wave,
-            max_decode_block=args.max_decode_block,
-            top_p=args.top_p, top_k=args.top_k, min_p=args.min_p,
-            prefill_chunk=args.prefill_chunk,
-            max_prefill_buckets=args.max_prefill_buckets,
-            sched_policy=args.sched_policy,
-            preemption=args.preemption,
-            max_preemptions=args.max_preemptions,
-            speculative_fill=not args.no_spec_fill,
-            aging_s=args.aging_s,
-            faults=faults,
-            kv_layout=args.kv_layout,
-            kv_page_size=args.kv_page_size,
-            kv_num_pages=args.kv_num_pages,
-            kv_dtype=args.kv_dtype,
-            spec_mode=args.spec_mode,
-            spec_k=args.spec_k,
-            spec_draft_config=spec_draft)
-        admission = None
-        if not args.no_admission:
-            admission = AdmissionController(
-                tenants=dict(parse_tenant_spec(s) for s in args.tenant),
-                max_queue_depth=args.max_queue_depth,
-                queue_timeout_s=args.queue_timeout,
-                shed_queue_depth=args.shed_queue_depth,
-                shed_wait_s=args.shed_wait)
-        return EngineClient(
-            engine, admission=admission,
-            watchdog_timeout_s=(args.watchdog_timeout
-                                if args.watchdog_timeout > 0 else None))
-
     if args.replicas > 1:
-        client = Router([build_replica() for _ in range(args.replicas)],
+        client = Router([build_replica(args, cfg, index=i,
+                                       spec_draft=spec_draft, faults=faults)
+                         for i in range(args.replicas)],
                         policy=args.router_policy, seed=args.seed)
         print(f"router: {args.replicas} replicas, "
               f"policy={args.router_policy}")
     else:
-        client = build_replica()
+        client = build_replica(args, cfg, spec_draft=spec_draft,
+                               faults=faults)
     api = OpenAIServer(client, cfg.name)
     if args.transport == "asgi":
         server = AsgiServer(api, port=args.port)

@@ -216,6 +216,24 @@ def test_saturated_headroom_escalates_soft_shed():
         ctl.submit(_req("t", interactive=True))
 
 
+@pytest.mark.parametrize("served,idle_s", [(1, 0.0), (2, 60.0)])
+def test_burst_after_cold_request_is_not_shed(served, idle_s):
+    """The release rate counts backlogged time only: requests served
+    promptly, then (after an idle gap) a burst, must all be admitted —
+    neither a single release nor the idle gap between requests may read
+    as a stalled server."""
+    clock = FakeClock()
+    ctl = _ctl(clock, shed_wait_s=10.0)
+    for _ in range(served):
+        clock.advance(idle_s)
+        ctl.submit(_req("t"))
+        clock.advance(0.01)
+        assert len(ctl.poll(8)[0]) == 1
+    for _ in range(6):
+        ctl.submit(_req("t"))
+    assert ctl.level == LEVEL_NORMAL
+
+
 def test_drain_is_terminal_and_finishes_queued_work():
     clock = FakeClock()
     ctl = _ctl(clock)

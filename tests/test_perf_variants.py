@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.distributed import use_sharding
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_step_spec, shape_rules
 import repro.launch.specs as specs_mod
 
@@ -25,7 +26,7 @@ def tiny_shapes():
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("moe_shard", ["fsdp", "2d", "ep"])

@@ -10,12 +10,13 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed import default_rules, param_shardings, use_sharding
 from repro.distributed.sharding import sanitize_spec
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_step_spec, shape_rules
 from repro.models import build_model
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_specs_assigned_by_name():
@@ -43,7 +44,7 @@ def test_stacked_leading_dims_get_none():
 
 
 def test_sanitize_spec_drops_nondivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # 1x1 mesh divides everything — use shape logic directly via a fake
     spec = sanitize_spec(P("data", "model"), (10, 16), mesh)
     assert spec == P("data", "model")               # 1 divides all
