@@ -103,6 +103,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs import ModelConfig
 from repro.core.content_cache import (ContentCache, CrossKVEntry,
                                       EmbeddingEntry, MediaStats,
@@ -125,6 +126,7 @@ from repro.core.spec_decode import (DraftModelSource, DraftSource,
                                     SpecStats, build_spec_verify_fn,
                                     stage_drafts)
 from repro.core.streaming import StopSequenceChecker, TokenStreamDecoder
+from repro.kernels import paged_attention
 from repro.models import build_model
 from repro.models.model import init_cache
 from repro.serving.media import AudioEncoderStub, VisionEncoderStub, decode_media
@@ -141,6 +143,14 @@ def _next_bucket(n: int, floor: int = 16) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _page_sum(c: int, page_size: int, cap: int) -> int:
+    """Σ over contexts j = 1..c of the pages a j-token context fills,
+    min(cap, ceil(j / page_size)), in closed form."""
+    full = min(c, cap * page_size)
+    q, r = divmod(full, page_size)
+    return page_size * q * (q + 1) // 2 + r * (q + 1) + cap * (c - full)
 
 
 @dataclass
@@ -451,6 +461,8 @@ class InferenceEngine:
         self._dummy_single = None            # zero cache row for wave padding
 
         self._step_count = 0
+        self._decode_blocks = 0          # decode blocks dispatched
+        self._synced_at: Optional[float] = None   # last block's token sync
         self._prefill_fns: Dict[Tuple, Any] = {}
         self._decode_block_fn = self._build_decode_block_fn()
 
@@ -1055,6 +1067,41 @@ class InferenceEngine:
                 out[slot] = (len(req.prompt_tokens) + req.num_generated - 1)
         return out
 
+    def _account_decode_block(self, k: int) -> Dict[str, int]:
+        """Count the decode block about to be dispatched: the host gap since
+        the previous block's token sync, and the work of its K steps, which
+        the ``engine.decode_dispatch`` span carries: live slots, the tokens
+        they decode (min(K, budget left) each) and the contexts those
+        tokens attend.  Under the paged layout, per step, the pages that
+        hold live context and the pages ``paged_attention``'s grid visits.
+        O(live slots): each slot's sums over the block are closed forms."""
+        stats = self.scheduler.stats
+        if self._synced_at is not None:
+            stats.host_gap_s += time.perf_counter() - self._synced_at
+            stats.host_gaps += 1
+            self._synced_at = None
+        self._decode_blocks += 1
+        live = tokens = ctx = pages = 0
+        if self._paged:
+            ps, cap = self.pool.page_size, self.pool.pages_per_slot
+        for slot, req in self.scheduler.active.items():
+            if slot not in self._live_slots or req.is_finished:
+                continue
+            done = req.num_generated
+            pos = len(req.prompt_tokens) + done - 1       # the token fed in
+            n = max(0, min(k, req.sampling.max_tokens - done))
+            live += 1
+            tokens += n
+            # step s feeds position pos + s, which attends pos + 1 + s cells
+            ctx += n * (pos + 1) + n * (n - 1) // 2
+            if self._paged:
+                pages += _page_sum(pos + n, ps, cap) - _page_sum(pos, ps, cap)
+        if self._paged:
+            b, p = paged_attention.grid(self.pool.max_batch, cap)
+            stats.kv_live_pages += pages
+            stats.kv_grid_pages += k * b * p
+        return {"live": live, "tokens": tokens, "ctx_tokens": ctx}
+
     def _ensure_paged_capacity(self, k_steps: int) -> None:
         """Pressure ladder before a decode block: make the pages the block
         will write exclusively owned (lazy tail allocation + COW splits).
@@ -1514,15 +1561,21 @@ class InferenceEngine:
                                   + [zero_v] * (kp - k), axis=0)
 
         fn = self._prefill_fn(bucket, kp, cross_cached)
-        logits, out_cache = fn(
-            self.params, jnp.asarray(toks), jnp.asarray(poss),
-            tuple(singles),
-            jnp.asarray(media) if media is not None else None,
-            jnp.asarray(ctxv) if ctxv is not None else None,
-            jnp.asarray(valid), jnp.asarray(last_idx))
+        real = sum(take for _, take in rows)
+        pad = bucket * kp - real
+        with tracing.span("engine.prefill_dispatch", bucket=bucket, rows=k,
+                          padded_rows=kp, tokens=real, pad_tokens=pad):
+            logits, out_cache = fn(
+                self.params, jnp.asarray(toks), jnp.asarray(poss),
+                tuple(singles),
+                jnp.asarray(media) if media is not None else None,
+                jnp.asarray(ctxv) if ctxv is not None else None,
+                jnp.asarray(valid), jnp.asarray(last_idx))
         stats = self.scheduler.stats
         stats.prefill_waves += 1
         stats.prefill_chunks += k
+        stats.prefill_tokens += real
+        stats.prefill_pad_tokens += pad
 
         done: List[Tuple[_PrefillJob, jax.Array]] = []
         for i, (job, take) in enumerate(rows):
@@ -1616,6 +1669,10 @@ class InferenceEngine:
                 req.prefill_time = now - job.t0
             if req.first_token_time is None:
                 req.first_token_time = now
+                if req.bind_time is not None:
+                    stats = self.scheduler.stats
+                    stats.prefill_wait_s += now - req.bind_time
+                    stats.first_tokens += 1
             req.output_tokens.append(int(first))
             logprob = top = None
             if lp is not None and req.sampling.logprobs:
@@ -2448,6 +2505,10 @@ class InferenceEngine:
         the decode block's token sync — so wave compute executes behind the
         host-sync window instead of stalling the decode loop.
         """
+        with tracing.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> List[StreamEvent]:
         events: List[StreamEvent] = []
         self._fault_tick += 1
         if (self.faults is not None
@@ -2456,7 +2517,10 @@ class InferenceEngine:
             time.sleep(self.faults.slow_step_s)
 
         # 1. bind pending requests to slots; open prefill jobs
-        self._plan_admissions()
+        with tracing.span("engine.admit"):
+            self._plan_admissions()
+        if not self._live_slots:
+            self._synced_at = None       # the decode stream paused
 
         # 2. dispatch one compiled block of K decode steps (no host block
         # yet); K collapses to 1 while requests, chunks, or — via the
@@ -2485,11 +2549,16 @@ class InferenceEngine:
                     # pages: allocate tails / COW-split shared pages now,
                     # under the page-pressure ladder (can shrink
                     # _live_slots)
-                    self._ensure_paged_capacity(num_steps)
+                    with tracing.span("engine.kv_reserve"):
+                        self._ensure_paged_capacity(num_steps)
+                work = self._account_decode_block(num_steps)
                 try:
-                    cache, state, toks, lps = self._decode_block_fn(
-                        self.params, self.pool.cache, self.state,
-                        num_steps=num_steps, want_logprobs=want_lp)
+                    with tracing.span("engine.decode_dispatch",
+                                      block=self._decode_blocks - 1,
+                                      k=num_steps, **work):
+                        cache, state, toks, lps = self._decode_block_fn(
+                            self.params, self.pool.cache, self.state,
+                            num_steps=num_steps, want_logprobs=want_lp)
                 except Exception as e:  # catastrophic block failure
                     self._recover_decode_block(e)
                 else:
@@ -2507,10 +2576,12 @@ class InferenceEngine:
         # 4. sync the token block; emit + retire step-major
         if block_plan is not None:
             num_steps, toks, lps = block_plan
-            block = np.asarray(toks)              # [K, B]: the block's one sync
-            lp_c = lp_v = lp_i = None
-            if lps is not None:
-                lp_c, lp_v, lp_i = (np.asarray(a) for a in lps)
+            with tracing.span("engine.sync"):
+                block = np.asarray(toks)          # [K, B]: the block's one sync
+                lp_c = lp_v = lp_i = None
+                if lps is not None:
+                    lp_c, lp_v, lp_i = (np.asarray(a) for a in lps)
+            self._synced_at = time.perf_counter()
             self._step_count += 1
             self.scheduler.stats.steps += 1
             # one spec round is ONE device dispatch however many rows it
@@ -2519,46 +2590,47 @@ class InferenceEngine:
                 (1 if spec_meta is not None else num_steps)
             if spec_meta is not None:
                 self._account_spec_round(spec_meta)
-            live = {s: r for s, r in self.scheduler.active.items()
-                    if s in self._live_slots}
-            for k in range(num_steps):
-                for slot in sorted(live):
-                    req = live[slot]
-                    if req.is_finished:
-                        continue
-                    tok = int(block[k, slot])
-                    if tok < 0:
-                        # frozen-slot sentinel: the device finish-mask fired
-                        # but the host hasn't (belt and braces — the two
-                        # conditions are equivalent by construction)
-                        continue
-                    if tok >= self.cfg.vocab_size or (
-                            self.faults is not None
-                            and self.faults.fires("decode", req.request_id,
-                                                  req.num_generated)):
-                        # corrupt sampled token (the NaN-in-logits scenario,
-                        # or its injected stand-in): fail this request only;
-                        # neighbour slots are independent (per-slot RNG,
-                        # masked cache writes) and continue bit-identically
-                        self._fault_events.extend(self._fail_request(
-                            req.request_id,
-                            f"corrupt token {tok} at position "
-                            f"{req.num_generated}"))
-                        continue
-                    req.output_tokens.append(tok)
-                    self.scheduler.stats.tokens_generated += 1
-                    logprob = top = None
-                    if lp_c is not None and req.sampling.logprobs:
-                        logprob = float(lp_c[k, slot])
-                        ntop = req.sampling.top_logprobs
-                        top = list(zip(lp_i[k, slot, :ntop].tolist(),
-                                       lp_v[k, slot, :ntop].tolist()))
-                    try:
-                        events.extend(
-                            self._emit_token(slot, req, tok, logprob, top))
-                    except Exception as e:  # per-request boundary (codec)
-                        self._fault_events.extend(self._fail_request(
-                            req.request_id, f"codec failure: {e}"))
+            with tracing.span("engine.emit"):
+                live = {s: r for s, r in self.scheduler.active.items()
+                        if s in self._live_slots}
+                for k in range(num_steps):
+                    for slot in sorted(live):
+                        req = live[slot]
+                        if req.is_finished:
+                            continue
+                        tok = int(block[k, slot])
+                        if tok < 0:
+                            # frozen-slot sentinel: the device finish-mask fired
+                            # but the host hasn't (belt and braces — the two
+                            # conditions are equivalent by construction)
+                            continue
+                        if tok >= self.cfg.vocab_size or (
+                                self.faults is not None
+                                and self.faults.fires("decode", req.request_id,
+                                                      req.num_generated)):
+                            # corrupt sampled token (the NaN-in-logits scenario,
+                            # or its injected stand-in): fail this request only;
+                            # neighbour slots are independent (per-slot RNG,
+                            # masked cache writes) and continue bit-identically
+                            self._fault_events.extend(self._fail_request(
+                                req.request_id,
+                                f"corrupt token {tok} at position "
+                                f"{req.num_generated}"))
+                            continue
+                        req.output_tokens.append(tok)
+                        self.scheduler.stats.tokens_generated += 1
+                        logprob = top = None
+                        if lp_c is not None and req.sampling.logprobs:
+                            logprob = float(lp_c[k, slot])
+                            ntop = req.sampling.top_logprobs
+                            top = list(zip(lp_i[k, slot, :ntop].tolist(),
+                                           lp_v[k, slot, :ntop].tolist()))
+                        try:
+                            events.extend(
+                                self._emit_token(slot, req, tok, logprob, top))
+                        except Exception as e:  # per-request boundary (codec)
+                            self._fault_events.extend(self._fail_request(
+                                req.request_id, f"codec failure: {e}"))
 
         # 5. land finished prefills (next block picks the new slots up);
         # speculative jobs whose slot arrived this step commit in the same
@@ -2566,7 +2638,9 @@ class InferenceEngine:
         ready = [(j, j.logits) for j in self._ready_jobs]
         self._ready_jobs.clear()
         try:
-            events.extend(self._commit_jobs(ready + completed))
+            with tracing.span("engine.commit",
+                              jobs=len(ready) + len(completed)):
+                events.extend(self._commit_jobs(ready + completed))
         except Exception as e:  # commit-wave fault boundary
             log.warning("admission commit failed (%d jobs): %s",
                         len(ready) + len(completed), e)

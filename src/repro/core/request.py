@@ -147,6 +147,11 @@ class Request:
     # times this request was evicted from a decode slot by a more urgent
     # request (scheduler preemption); bounds re-eviction churn
     preempt_count: int = 0
+    # monotonic times of the last preemption back to the queue (None: never
+    # preempted, the request has queued since arrival_time) and of the last
+    # slot bind; the scheduler's queue_wait_s / prefill_wait_s read them
+    requeue_time: Optional[float] = None
+    bind_time: Optional[float] = None
     # human-readable failure detail when finish_reason is ERROR/TIMEOUT
     # (carried on the terminal StreamEvent's text is user output, so the
     # diagnostic lives here instead)

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 def request_base_key(seed: int) -> np.ndarray:
     """Base PRNG key for a seeded request: depends on the seed alone (never on
@@ -148,27 +150,28 @@ def masked_sample_inner(
     ``split`` per step; this pays nothing); a second inner ``cond`` lets
     plain temperature sampling (all mask knobs off) skip the sort pipeline
     too."""
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with tracing.scope("sampler"):
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def stochastic(_):
-        keys = fold_step_keys(base_keys, positions)
-        temps = jnp.maximum(temperatures, 1e-6)[:, None]
-        scaled = logits / temps
+        def stochastic(_):
+            keys = fold_step_keys(base_keys, positions)
+            temps = jnp.maximum(temperatures, 1e-6)[:, None]
+            scaled = logits / temps
 
-        def masked(_):
-            return mask_scaled_logits(scaled, top_p, top_k, min_p)
+            def masked(_):
+                return mask_scaled_logits(scaled, top_p, top_k, min_p)
 
-        # second fast path: plain temperature sampling (every mask knob off)
-        # skips the O(B·V log V) sort pipeline and draws straight from the
-        # scaled logits — bit-identical to the masked path, whose no-op
-        # masks leave `scaled` bitwise unchanged
-        any_mask = jnp.any((top_k > 0) | (top_p < 1.0) | (min_p > 0.0))
-        target = jax.lax.cond(any_mask, masked, lambda _: scaled, operand=None)
-        sampled = jax.vmap(jax.random.categorical)(keys, target).astype(jnp.int32)
-        return jnp.where(temperatures > 0, sampled, greedy)
+            # second fast path: plain temperature sampling (every mask knob off)
+            # skips the O(B·V log V) sort pipeline and draws straight from the
+            # scaled logits — bit-identical to the masked path, whose no-op
+            # masks leave `scaled` bitwise unchanged
+            any_mask = jnp.any((top_k > 0) | (top_p < 1.0) | (min_p > 0.0))
+            target = jax.lax.cond(any_mask, masked, lambda _: scaled, operand=None)
+            sampled = jax.vmap(jax.random.categorical)(keys, target).astype(jnp.int32)
+            return jnp.where(temperatures > 0, sampled, greedy)
 
-    return jax.lax.cond(jnp.any(temperatures > 0), stochastic, lambda _: greedy, operand=None)
+        return jax.lax.cond(jnp.any(temperatures > 0), stochastic, lambda _: greedy, operand=None)
 
 
 masked_sample = jax.jit(masked_sample_inner)

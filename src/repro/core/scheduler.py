@@ -186,6 +186,24 @@ class SchedulerStats:
     aborted: int = 0             # requests cancelled before finishing
     failed: int = 0              # requests failed by per-request fault
                                  # isolation (prefill/decode/codec errors)
+    # Σ (slot bind − time the request joined the queue: arrival, or its
+    # preemption) over admissions; per admission: / admitted
+    queue_wait_s: float = 0.0
+    # Σ (first token − slot bind) over the requests counted in first_tokens
+    prefill_wait_s: float = 0.0
+    first_tokens: int = 0
+    # Σ of the host time from one decode block's token sync returning to
+    # the next block's dispatch while slots stay live, and how many gaps
+    host_gap_s: float = 0.0
+    host_gaps: int = 0
+    # prompt tokens the prefill waves advanced, and the padding their
+    # (bucket × power-of-two rows) shapes added
+    prefill_tokens: int = 0
+    prefill_pad_tokens: int = 0
+    # Σ over decode steps (paged layout) of the pages holding a live slot's
+    # context, and of the pages one layer's paged_attention grid visits
+    kv_live_pages: int = 0
+    kv_grid_pages: int = 0
 
     @property
     def host_syncs_per_token(self) -> float:
@@ -269,6 +287,7 @@ class ContinuousBatchingScheduler:
         ``eligible`` mirrors :meth:`peek_pending` — ineligible requests stay
         queued without losing their policy-order position."""
         admitted = []
+        now = time.monotonic()
         for slot in free_slots:
             if len(self.active) >= self.max_batch:
                 break
@@ -278,6 +297,10 @@ class ContinuousBatchingScheduler:
             self.active[slot] = req
             admitted.append((slot, req))
             self.stats.admitted += 1
+            queued = (req.arrival_time if req.requeue_time is None
+                      else req.requeue_time)
+            self.stats.queue_wait_s += now - queued
+            req.bind_time = now
         self.stats.peak_batch = max(self.stats.peak_batch, len(self.active))
         return admitted
 
@@ -339,6 +362,7 @@ class ContinuousBatchingScheduler:
         eviction resumable; here it is pure bookkeeping."""
         req = self.active.pop(slot)
         req.preempt_count += 1
+        req.requeue_time = time.monotonic()
         self.stats.preemptions += 1
         self.pending.append(req)
         return req
@@ -502,6 +526,15 @@ class ContinuousBatchingScheduler:
             "resumed": s.resumed,
             "aborted": s.aborted,
             "failed": s.failed,
+            "queue_wait_s": s.queue_wait_s,
+            "prefill_wait_s": s.prefill_wait_s,
+            "first_tokens": s.first_tokens,
+            "host_gap_s": s.host_gap_s,
+            "host_gaps": s.host_gaps,
+            "prefill_tokens": s.prefill_tokens,
+            "prefill_pad_tokens": s.prefill_pad_tokens,
+            "kv_live_pages": s.kv_live_pages,
+            "kv_grid_pages": s.kv_grid_pages,
             "aging_s": self.policy.aging_s,
             "latency_by_class": self.latency_by_class(),
         }

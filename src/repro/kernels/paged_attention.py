@@ -37,6 +37,12 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels.ref import NEG_INF
 
 
+def grid(batch: int, pages_per_slot: int) -> tuple:
+    """The kernel's grid: one step per (slot, page the slot can hold),
+    whether the page holds live context or not."""
+    return (batch, pages_per_slot)
+
+
 def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
                   num_pages, ps, hkv, d, int8):
     if int8:
@@ -131,7 +137,7 @@ def paged_attention_pallas(
                                num_pages=p, ps=ps, hkv=hkv, d=d, int8=int8)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, p),
+        grid=grid(b, p),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, g, d), q_map),
         scratch_shapes=[

@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs import ModelConfig
 from repro.distributed import constrain
 from repro.kernels import ops
@@ -75,9 +76,10 @@ def init_mlp(key, d_model: int, d_ff: int, dtype) -> Params:
 
 
 def apply_mlp(p: Params, x: jax.Array) -> jax.Array:
-    h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
-    h = constrain(h, "batch", *((None,) * (h.ndim - 2)), "tp")
-    return h @ p["w_down"]
+    with tracing.scope("mlp"):
+        h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
+        h = constrain(h, "batch", *((None,) * (h.ndim - 2)), "tp")
+        return h @ p["w_down"]
 
 
 # --------------------------------------------------------------------------- #
@@ -146,16 +148,18 @@ def apply_self_attn(
         # right-padding leaves no trace in the cache (the final cache is
         # bit-identical however the prompt was bucketed or chunked).
         sc = cache["k"].shape[1]
-        bidx = jnp.arange(b)[:, None]
-        slots = (positions % sc).astype(jnp.int32)                      # [B,S]
-        if seq_valid is not None:
-            keep = seq_valid[..., None, None]
-            k = jnp.where(keep, k, cache["k"][bidx, slots])
-            v = jnp.where(keep, v, cache["v"][bidx, slots])
-        kc = cache["k"].at[bidx, slots].set(k)
-        vc = cache["v"].at[bidx, slots].set(v)
-        out = ops.flash_attention(q, kc, vc, causal=True, window=window,
-                                  q_positions=positions)
+        with tracing.scope("kv_arena"):
+            bidx = jnp.arange(b)[:, None]
+            slots = (positions % sc).astype(jnp.int32)                  # [B,S]
+            if seq_valid is not None:
+                keep = seq_valid[..., None, None]
+                k = jnp.where(keep, k, cache["k"][bidx, slots])
+                v = jnp.where(keep, v, cache["v"][bidx, slots])
+            kc = cache["k"].at[bidx, slots].set(k)
+            vc = cache["v"].at[bidx, slots].set(v)
+        with tracing.scope("attention_kernel"):
+            out = ops.flash_attention(q, kc, vc, causal=True, window=window,
+                                      q_positions=positions)
         out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
         out = constrain(out, "batch", None, "tp")
         return x + out @ p["wo"], {"k": kc, "v": vc}
@@ -188,27 +192,27 @@ def apply_self_attn(
             outs = []
             for j in range(s):
                 pos_j = positions[:, j]
-                ring = (pos_j % sc).astype(jnp.int32)
-                page = page_table[bidx, ring // ps]
-                off = ring % ps
-                ok = live if seq_valid is None else live & seq_valid[:, j]
-                page = jnp.where(ok, page, (bidx // ps).astype(page.dtype))
-                off = jnp.where(ok, off, (bidx % ps).astype(off.dtype))
-                if ksc is not None:                     # int8 arena
-                    kq, ks_j = quantize_kv_int8(k[:, j])
-                    vq, vs_j = quantize_kv_int8(v[:, j])
-                    kc = kc.at[page, off].set(kq)
-                    vc = vc.at[page, off].set(vq)
-                    ksc = ksc.at[page, off].set(ks_j)
-                    vsc = vsc.at[page, off].set(vs_j)
+                with tracing.scope("kv_arena"):
+                    ring = (pos_j % sc).astype(jnp.int32)
+                    page = page_table[bidx, ring // ps]
+                    off = ring % ps
+                    ok = live if seq_valid is None else live & seq_valid[:, j]
+                    page = jnp.where(ok, page, (bidx // ps).astype(page.dtype))
+                    off = jnp.where(ok, off, (bidx % ps).astype(off.dtype))
+                    if ksc is not None:                     # int8 arena
+                        kq, ks_j = quantize_kv_int8(k[:, j])
+                        vq, vs_j = quantize_kv_int8(v[:, j])
+                        kc = kc.at[page, off].set(kq)
+                        vc = vc.at[page, off].set(vq)
+                        ksc = ksc.at[page, off].set(ks_j)
+                        vsc = vsc.at[page, off].set(vs_j)
+                    else:
+                        kc = kc.at[page, off].set(k[:, j])
+                        vc = vc.at[page, off].set(v[:, j])
+                with tracing.scope("attention_kernel"):
                     outs.append(ops.paged_attention(
                         q[:, j], kc, vc, page_table, pos_j,
                         k_scale=ksc, v_scale=vsc))
-                else:
-                    kc = kc.at[page, off].set(k[:, j])
-                    vc = vc.at[page, off].set(v[:, j])
-                    outs.append(ops.paged_attention(q[:, j], kc, vc,
-                                                    page_table, pos_j))
             out = jnp.stack(outs, axis=1)
             new_cache = ({"k": kc, "v": vc} if ksc is None else
                          {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})
@@ -216,30 +220,32 @@ def apply_self_attn(
             out = constrain(out, "batch", None, "tp")
             return x + out @ p["wo"], new_cache
         pos = positions[:, 0]  # S == 1: the block-decode fast path
-        ring = (pos % sc).astype(jnp.int32)
-        page_idx = ring // ps
-        off = ring % ps
-        page = page_table[bidx, page_idx]
-        if slot_active is not None:
-            page = jnp.where(slot_active, page,
-                             (bidx // ps).astype(page.dtype))
-            off = jnp.where(slot_active, off, (bidx % ps).astype(off.dtype))
-        if "k_scale" in cache:                          # int8 arena
-            kq, ks = quantize_kv_int8(k[:, 0])
-            vq, vs = quantize_kv_int8(v[:, 0])
-            kc = kc.at[page, off].set(kq)
-            vc = vc.at[page, off].set(vq)
-            ksc = cache["k_scale"].at[page, off].set(ks)
-            vsc = cache["v_scale"].at[page, off].set(vs)
+        ksc = vsc = None
+        with tracing.scope("kv_arena"):
+            ring = (pos % sc).astype(jnp.int32)
+            page_idx = ring // ps
+            off = ring % ps
+            page = page_table[bidx, page_idx]
+            if slot_active is not None:
+                page = jnp.where(slot_active, page,
+                                 (bidx // ps).astype(page.dtype))
+                off = jnp.where(slot_active, off,
+                                (bidx % ps).astype(off.dtype))
+            if "k_scale" in cache:                          # int8 arena
+                kq, ks = quantize_kv_int8(k[:, 0])
+                vq, vs = quantize_kv_int8(v[:, 0])
+                kc = kc.at[page, off].set(kq)
+                vc = vc.at[page, off].set(vq)
+                ksc = cache["k_scale"].at[page, off].set(ks)
+                vsc = cache["v_scale"].at[page, off].set(vs)
+            else:
+                kc = kc.at[page, off].set(k[:, 0])
+                vc = vc.at[page, off].set(v[:, 0])
+        with tracing.scope("attention_kernel"):
             out = ops.paged_attention(q[:, 0], kc, vc, page_table, pos,
                                       k_scale=ksc, v_scale=vsc)[:, None]
-            new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
-        else:
-            kc = kc.at[page, off].set(k[:, 0])
-            vc = vc.at[page, off].set(v[:, 0])
-            out = ops.paged_attention(q[:, 0], kc, vc, page_table,
-                                      pos)[:, None]
-            new_cache = {"k": kc, "v": vc}
+        new_cache = ({"k": kc, "v": vc} if ksc is None else
+                     {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})
     elif mode == "decode" and s > 1:
         # speculative verification: S = k_draft + 1 candidate tokens per slot
         # run as ONE batched decode forward.  Writes take the prefill-resume
@@ -256,52 +262,58 @@ def apply_self_attn(
         # j' > j in this same pass.
         kc, vc = cache["k"], cache["v"]
         sc = kc.shape[1]
-        bidx2 = jnp.arange(b)[:, None]
-        slots = (positions % sc).astype(jnp.int32)                      # [B,S]
-        if seq_valid is not None:
-            keep = seq_valid[..., None, None]
-            k = jnp.where(keep, k, kc[bidx2, slots])
-            v = jnp.where(keep, v, vc[bidx2, slots])
-        kc = kc.at[bidx2, slots].set(k)
-        vc = vc.at[bidx2, slots].set(v)
-        kc = constrain(kc, "kv_batch", "kv_seq", None, None)
-        vc = constrain(vc, "kv_batch", "kv_seq", None, None)
+        with tracing.scope("kv_arena"):
+            bidx2 = jnp.arange(b)[:, None]
+            slots = (positions % sc).astype(jnp.int32)                  # [B,S]
+            if seq_valid is not None:
+                keep = seq_valid[..., None, None]
+                k = jnp.where(keep, k, kc[bidx2, slots])
+                v = jnp.where(keep, v, vc[bidx2, slots])
+            kc = kc.at[bidx2, slots].set(k)
+            vc = vc.at[bidx2, slots].set(v)
+            kc = constrain(kc, "kv_batch", "kv_seq", None, None)
+            vc = constrain(vc, "kv_batch", "kv_seq", None, None)
         idx = jnp.arange(sc)[None, :]
         outs = []
         for j in range(s):
             pos_j = positions[:, j]
             valid_j = (idx <= pos_j[:, None]) | (pos_j[:, None] >= sc)
-            outs.append(ops.decode_attention(q[:, j], kc, vc, valid_j))
+            with tracing.scope("attention_kernel"):
+                outs.append(ops.decode_attention(q[:, j], kc, vc, valid_j))
         out = jnp.stack(outs, axis=1)                                   # [B,S,H,hd]
         new_cache = {"k": kc, "v": vc}
     elif mode == "decode":
         kc, vc = cache["k"], cache["v"]
         sc = kc.shape[1]
-        slot = (positions[:, 0] % sc).astype(jnp.int32)                 # [B]
-        bidx = jnp.arange(b)
-        kc = kc.at[bidx, slot].set(k[:, 0])
-        vc = vc.at[bidx, slot].set(v[:, 0])
-        kc = constrain(kc, "kv_batch", "kv_seq", None, None)
-        vc = constrain(vc, "kv_batch", "kv_seq", None, None)
+        with tracing.scope("kv_arena"):
+            slot = (positions[:, 0] % sc).astype(jnp.int32)             # [B]
+            bidx = jnp.arange(b)
+            kc = kc.at[bidx, slot].set(k[:, 0])
+            vc = vc.at[bidx, slot].set(v[:, 0])
+            kc = constrain(kc, "kv_batch", "kv_seq", None, None)
+            vc = constrain(vc, "kv_batch", "kv_seq", None, None)
         pos = positions[:, 0]
         idx = jnp.arange(sc)[None, :]
         valid = (idx <= pos[:, None]) | (pos[:, None] >= sc)            # ring
-        out = ops.decode_attention(q[:, 0], kc, vc, valid)[:, None]     # [B,1,H,hd]
+        with tracing.scope("attention_kernel"):
+            out = ops.decode_attention(q[:, 0], kc, vc, valid)[:, None]  # [B,1,H,hd]
         new_cache = {"k": kc, "v": vc}
     else:
-        out = ops.flash_attention(q, k, v, causal=True, window=window,
-                                  schedule=attn_schedule)
+        with tracing.scope("attention_kernel"):
+            out = ops.flash_attention(q, k, v, causal=True, window=window,
+                                      schedule=attn_schedule)
         new_cache = None
         if mode == "prefill":
             sc = cache["k"].shape[1]
             take = min(s, sc)
-            src_k = k[:, s - take:]
-            src_v = v[:, s - take:]
-            slots = ((s - take + jnp.arange(take)) % sc).astype(jnp.int32)
-            kc = cache["k"].at[:, slots].set(src_k)
-            vc = cache["v"].at[:, slots].set(src_v)
-            kc = constrain(kc, "kv_batch", "kv_seq", None, None)
-            vc = constrain(vc, "kv_batch", "kv_seq", None, None)
+            with tracing.scope("kv_arena"):
+                src_k = k[:, s - take:]
+                src_v = v[:, s - take:]
+                slots = ((s - take + jnp.arange(take)) % sc).astype(jnp.int32)
+                kc = cache["k"].at[:, slots].set(src_k)
+                vc = cache["v"].at[:, slots].set(src_v)
+                kc = constrain(kc, "kv_batch", "kv_seq", None, None)
+                vc = constrain(vc, "kv_batch", "kv_seq", None, None)
             new_cache = {"k": kc, "v": vc}
 
     out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)

@@ -21,6 +21,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs import ModelConfig
 from repro.distributed import constrain
 from repro.models import layers
@@ -220,28 +221,35 @@ class Model:
         if pattern and unroll_scan:
             ys = []
             for g in range(repeats):
-                xs_g = jax.tree.map(lambda a: a[g],
-                                    (params["block"], cache_xs))
-                x, y = body(x, xs_g)
+                p_g = jax.tree.map(lambda a: a[g], params["block"])
+                with tracing.scope("kv_arena"):
+                    c_g = jax.tree.map(lambda a: a[g], cache_xs)
+                x, y = body(x, (p_g, c_g))
                 ys.append(y)
             caches_g = [y[0] for y in ys]
             aux_total += sum(y[1] for y in ys)
-            new_block_cache = (jax.tree.map(
-                lambda *leaves: jnp.stack(leaves), *caches_g)
-                if caches_g[0] is not None else None)
+            with tracing.scope("kv_arena"):
+                new_block_cache = (jax.tree.map(
+                    lambda *leaves: jnp.stack(leaves), *caches_g)
+                    if caches_g[0] is not None else None)
         elif pattern:
+            # no scope here: the scan slices the stacked cache and restacks
+            # the updated one under the same op path as its slicing of the
+            # weights, so a trace tells the cache's part by its shape
             x, (new_block_cache, aux_g) = jax.lax.scan(
                 body, x, (params["block"], cache_xs))
             aux_total += aux_g.sum()
         else:
             new_block_cache = None
 
-        x = layers.rmsnorm(x, params["final_ln"], cfg.rms_eps)
-        if logits_mode == "last":        # prefill: only the final position's
-            x = x[:, -1:]                # logits are needed — skip S·D·V work
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        logits = x @ head
-        logits = constrain(logits, "batch", None, "vocab")
+        with tracing.scope("head"):
+            x = layers.rmsnorm(x, params["final_ln"], cfg.rms_eps)
+            if logits_mode == "last":    # prefill: only the final position's
+                x = x[:, -1:]            # logits are needed — skip S·D·V work
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = x @ head
+            logits = constrain(logits, "batch", None, "vocab")
 
         new_cache = None
         if mode == "prefill":

@@ -42,6 +42,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Tuple, Union
 
+from repro import tracing
 from repro.core.admission import (LEVEL_SHED_ALL, AdmissionController,
                                   Overloaded)
 from repro.core.engine import InferenceEngine
@@ -589,7 +590,8 @@ class EngineClient:
                     else:
                         events.extend(engine.abort(rid))
                 if self._admission is not None:
-                    events.extend(self._admission_round())
+                    with tracing.span("client.admission"):
+                        events.extend(self._admission_round())
                 if cutoff:
                     events.extend(self._drain_cutoff_events())
                 elif engine.scheduler.has_work:
@@ -604,7 +606,7 @@ class EngineClient:
                 time.sleep(0.05)        # no hot spin on persistent failure
             finally:
                 self._step_started = None
-            with self._cv:
+            with self._cv, tracing.span("client.deliver"):
                 for ev in events:
                     handle = self._handles.get(ev.request_id)
                     if handle is not None:
