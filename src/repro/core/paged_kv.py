@@ -48,10 +48,8 @@ import numpy as np
 from repro.configs import ModelConfig
 from repro.core.kv_cache import tree_bytes
 from repro.kernels.quant_matmul import quantize_kv_int8
+from repro.models.layers import ARENA_KEYS
 from repro.models.model import init_cache
-
-#: cache-dict keys that live in the page arena (everything else is dense)
-ARENA_KEYS = ("k", "v", "k_scale", "v_scale")
 
 
 class PagePoolExhausted(RuntimeError):

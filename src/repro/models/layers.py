@@ -29,6 +29,10 @@ from repro.kernels.quant_matmul import quantize_kv_int8
 
 Params = Dict[str, Any]
 
+#: cache-dict keys that live in the page arena under paged KV (everything
+#: else is dense per slot)
+ARENA_KEYS = ("k", "v", "k_scale", "v_scale")
+
 
 # --------------------------------------------------------------------------- #
 # primitives
@@ -131,6 +135,9 @@ def apply_self_attn(
     seq_valid: Optional[jax.Array] = None,   # [B, S] prefix mask (padding off)
     page_table: Optional[jax.Array] = None,  # [B, P] paged-KV decode only
     slot_active: Optional[jax.Array] = None,  # [B] live mask (paged decode)
+    layer: Optional[jax.Array] = None,  # paged decode: the arena leaves are
+                                     # stacked [L, N, ...] and this is the
+                                     # layer's index into them
 ) -> Tuple[jax.Array, Optional[Params]]:
     b, s, _ = x.shape
     h = rmsnorm(x, p["ln"], cfg.rms_eps)
@@ -171,79 +178,56 @@ def apply_self_attn(
         # exclusively-owned page; frozen slots (slot_active False) redirect
         # their write to a reserved per-slot trash cell so shared/retired
         # pages are never touched (the paged analogue of the dense path's
-        # select_cache_slots ring-cell repair).  No sharding constrain on
-        # the arena: paged + distributed KV is not supported.
+        # select_cache_slots ring-cell repair).  Inside the layer scan the
+        # arena leaves are the whole stack [L, N, ps, Hkv, hd]: the cells
+        # are written in place at ``layer`` and the kernel is handed that
+        # layer's slice alone.  No sharding constrain on the arena: paged +
+        # distributed KV is not supported.
         kc, vc = cache["k"], cache["v"]
-        ps = kc.shape[1]
+        ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
+        ps = kc.shape[-3]
         sc = ps * page_table.shape[1]
         bidx = jnp.arange(b)
-        if s > 1:
-            # speculative verification under paging: per-position writes and
-            # attention in a static Python loop (one compiled graph).  A
-            # cell whose row is frozen OR beyond the slot's staged drafts
-            # (``seq_valid`` False) redirects to the slot's reserved trash
-            # cell — real pages of rejected/invalid positions are written
-            # only for accepted drafts, and the verifier's rollback
-            # (paged_kv.restore_page_cells) restores the rest.  Same wrap
-            # guard as the dense branch (engine stages zero drafts on wrap).
-            ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
-            live = (slot_active if slot_active is not None
-                    else jnp.ones((b,), bool))
-            outs = []
-            for j in range(s):
-                pos_j = positions[:, j]
-                with tracing.scope("kv_arena"):
-                    ring = (pos_j % sc).astype(jnp.int32)
-                    page = page_table[bidx, ring // ps]
-                    off = ring % ps
-                    ok = live if seq_valid is None else live & seq_valid[:, j]
-                    page = jnp.where(ok, page, (bidx // ps).astype(page.dtype))
-                    off = jnp.where(ok, off, (bidx % ps).astype(off.dtype))
-                    if ksc is not None:                     # int8 arena
-                        kq, ks_j = quantize_kv_int8(k[:, j])
-                        vq, vs_j = quantize_kv_int8(v[:, j])
-                        kc = kc.at[page, off].set(kq)
-                        vc = vc.at[page, off].set(vq)
-                        ksc = ksc.at[page, off].set(ks_j)
-                        vsc = vsc.at[page, off].set(vs_j)
-                    else:
-                        kc = kc.at[page, off].set(k[:, j])
-                        vc = vc.at[page, off].set(v[:, j])
-                with tracing.scope("attention_kernel"):
-                    outs.append(ops.paged_attention(
-                        q[:, j], kc, vc, page_table, pos_j,
-                        k_scale=ksc, v_scale=vsc))
-            out = jnp.stack(outs, axis=1)
-            new_cache = ({"k": kc, "v": vc} if ksc is None else
-                         {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})
-            out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
-            out = constrain(out, "batch", None, "tp")
-            return x + out @ p["wo"], new_cache
-        pos = positions[:, 0]  # S == 1: the block-decode fast path
-        ksc = vsc = None
-        with tracing.scope("kv_arena"):
-            ring = (pos % sc).astype(jnp.int32)
-            page_idx = ring // ps
-            off = ring % ps
-            page = page_table[bidx, page_idx]
-            if slot_active is not None:
-                page = jnp.where(slot_active, page,
-                                 (bidx // ps).astype(page.dtype))
-                off = jnp.where(slot_active, off,
-                                (bidx % ps).astype(off.dtype))
-            if "k_scale" in cache:                          # int8 arena
-                kq, ks = quantize_kv_int8(k[:, 0])
-                vq, vs = quantize_kv_int8(v[:, 0])
-                kc = kc.at[page, off].set(kq)
-                vc = vc.at[page, off].set(vq)
-                ksc = cache["k_scale"].at[page, off].set(ks)
-                vsc = cache["v_scale"].at[page, off].set(vs)
-            else:
-                kc = kc.at[page, off].set(k[:, 0])
-                vc = vc.at[page, off].set(v[:, 0])
-        with tracing.scope("attention_kernel"):
-            out = ops.paged_attention(q[:, 0], kc, vc, page_table, pos,
-                                      k_scale=ksc, v_scale=vsc)[:, None]
+        lead = () if layer is None else (layer,)
+
+        def at_layer(a):
+            return a if layer is None or a is None else a[layer]
+
+        live = slot_active if slot_active is not None else jnp.ones((b,), bool)
+        outs = []
+        # S > 1 is speculative verification under paging: per-position
+        # writes and attention in a static Python loop (one compiled graph).
+        # A cell whose row is frozen OR beyond the slot's staged drafts
+        # (``seq_valid`` False) redirects to the slot's reserved trash cell —
+        # real pages of rejected/invalid positions are written only for
+        # accepted drafts, and the verifier's rollback
+        # (paged_kv.restore_page_cells) restores the rest.  Same wrap guard
+        # as the dense branch (engine stages zero drafts on wrap).
+        for j in range(s):
+            pos_j = positions[:, j]
+            with tracing.scope("kv_arena"):
+                ring = (pos_j % sc).astype(jnp.int32)
+                page = page_table[bidx, ring // ps]
+                off = ring % ps
+                ok = live if seq_valid is None else live & seq_valid[:, j]
+                page = jnp.where(ok, page, (bidx // ps).astype(page.dtype))
+                off = jnp.where(ok, off, (bidx % ps).astype(off.dtype))
+                at = lead + (page, off)
+                if ksc is not None:                         # int8 arena
+                    kq, ks_j = quantize_kv_int8(k[:, j])
+                    vq, vs_j = quantize_kv_int8(v[:, j])
+                    kc = kc.at[at].set(kq)
+                    vc = vc.at[at].set(vq)
+                    ksc = ksc.at[at].set(ks_j)
+                    vsc = vsc.at[at].set(vs_j)
+                else:
+                    kc = kc.at[at].set(k[:, j])
+                    vc = vc.at[at].set(v[:, j])
+            with tracing.scope("attention_kernel"):
+                outs.append(ops.paged_attention(
+                    q[:, j], at_layer(kc), at_layer(vc), page_table, pos_j,
+                    k_scale=at_layer(ksc), v_scale=at_layer(vsc)))
+        out = jnp.stack(outs, axis=1)                               # [B,S,H,hd]
         new_cache = ({"k": kc, "v": vc} if ksc is None else
                      {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})
     elif mode == "decode" and s > 1:
@@ -621,18 +605,20 @@ def apply_layer(
     seq_valid: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,
     slot_active: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).  ``layer`` indexes stacked paged
+    arena leaves (see :func:`apply_self_attn`)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Params = {}
     if "attn" in p:
-        sub = ({k: cache[k] for k in ("k", "v", "k_scale", "v_scale")
-                if k in cache} if cache else None)
+        sub = ({k: cache[k] for k in ARENA_KEYS if k in cache}
+               if cache else None)
         x, c = apply_self_attn(p["attn"], x, cfg=cfg, mode=mode,
                                positions=positions, cache=sub, window=window,
                                attn_schedule=attn_schedule, resume=resume,
                                seq_valid=seq_valid, page_table=page_table,
-                               slot_active=slot_active)
+                               slot_active=slot_active, layer=layer)
         if c:
             new_cache.update(c)
     if "cross" in p and kind != "xattn":    # audio decoder cross-attn

@@ -198,33 +198,50 @@ class Model:
             new_prefix_caches.append(c)
             aux_total += aux
 
-        def group_body(x, xs):
-            p_slice, c_slice = xs
+        def group_body(carry, xs):
+            x, arena = carry
+            p_slice, c_slice, layer = xs
             aux_g = jnp.zeros((), jnp.float32)
             c_out: Dict[str, Any] = {}
+            arena_out: Dict[str, Any] = {}
             for i, kind in enumerate(pattern):
-                sub = c_slice[f"pos{i}"] if c_slice is not None else None
+                pos = f"pos{i}"
+                sub = c_slice[pos] if c_slice is not None else None
+                if pos in arena:
+                    sub = {**sub, **arena[pos]}
                 x, c, aux = layers.apply_layer(
-                    p_slice[f"pos{i}"], kind, x, cfg=cfg, mode=mode,
+                    p_slice[pos], kind, x, cfg=cfg, mode=mode,
                     positions=positions, cache=sub, window=window_eff,
                     context=context, attn_schedule=attn_schedule,
                     resume=resume, cross_cached=cross_cached,
                     ctx_valid=ctx_valid, seq_valid=seq_valid,
-                    page_table=page_table, slot_active=slot_active)
+                    page_table=page_table, slot_active=slot_active,
+                    layer=layer if pos in arena else None)
+                if pos in arena:
+                    arena_out[pos] = {n: c.pop(n) for n in arena[pos]}
                 if c is not None:
-                    c_out[f"pos{i}"] = c
+                    c_out[pos] = c
                 aux_g += aux
-            return x, (c_out or None, aux_g)
+            return (x, arena_out), (c_out or None, aux_g)
 
         body = jax.checkpoint(group_body) if (remat and mode == "train") else group_body
         cache_xs = cache["block"] if cache is not None else None
+        # paged decode: the stacked page arenas ride the carry, each layer
+        # writes its cells in place at its index and the kernel reads that
+        # layer alone — as xs/ys the scan would slice every layer's arena
+        # out, stack the updated one back, and the decode loop would copy
+        # the whole stack into its own carry
+        arena: Dict[str, Any] = {}
+        if page_table is not None and cache_xs is not None:
+            arena, cache_xs = _split_arena(cache_xs)
         if pattern and unroll_scan:
             ys = []
             for g in range(repeats):
                 p_g = jax.tree.map(lambda a: a[g], params["block"])
                 with tracing.scope("kv_arena"):
                     c_g = jax.tree.map(lambda a: a[g], cache_xs)
-                x, y = body(x, (p_g, c_g))
+                (x, arena), y = body((x, arena),
+                                     (p_g, c_g, g if arena else None))
                 ys.append(y)
             caches_g = [y[0] for y in ys]
             aux_total += sum(y[1] for y in ys)
@@ -233,14 +250,15 @@ class Model:
                     lambda *leaves: jnp.stack(leaves), *caches_g)
                     if caches_g[0] is not None else None)
         elif pattern:
-            # no scope here: the scan slices the stacked cache and restacks
-            # the updated one under the same op path as its slicing of the
-            # weights, so a trace tells the cache's part by its shape
-            x, (new_block_cache, aux_g) = jax.lax.scan(
-                body, x, (params["block"], cache_xs))
+            layer_xs = jnp.arange(repeats, dtype=jnp.int32) if arena else None
+            (x, arena), (new_block_cache, aux_g) = jax.lax.scan(
+                body, (x, arena), (params["block"], cache_xs, layer_xs))
             aux_total += aux_g.sum()
         else:
             new_block_cache = None
+        if arena:
+            new_block_cache = {pos: {**sub, **arena.get(pos, {})}
+                               for pos, sub in new_block_cache.items()}
 
         with tracing.scope("head"):
             x = layers.rmsnorm(x, params["final_ln"], cfg.rms_eps)
@@ -257,6 +275,17 @@ class Model:
         elif mode == "decode":
             new_cache = {"prefix": new_prefix_caches, "block": new_block_cache}
         return ModelOutput(logits, new_cache, aux_total)
+
+
+def _split_arena(block: Params) -> Tuple[Params, Params]:
+    """A paged block cache's stacked page-arena leaves (per position that
+    has any) and the rest of it."""
+    arena = {pos: {n: a for n, a in sub.items() if n in layers.ARENA_KEYS}
+             for pos, sub in block.items()}
+    rest = {pos: {n: a for n, a in sub.items()
+                  if n not in layers.ARENA_KEYS}
+            for pos, sub in block.items()}
+    return {pos: a for pos, a in arena.items() if a}, rest
 
 
 def build_model(cfg: ModelConfig) -> Model:

@@ -18,6 +18,7 @@ from repro.core.engine import InferenceEngine
 from repro.core.paged_kv import PageAllocator, PagedKVPool, PagePoolExhausted
 from repro.core.request import FinishReason, Request, SamplingParams
 from repro.kernels.ref import decode_attention_ref, paged_attention_ref
+from repro.models import build_model
 from repro.serving.tokenizer import ByteTokenizer
 
 try:
@@ -344,6 +345,77 @@ def test_pool_ensure_capacity_allocates_tail_and_splits_shared(cfg):
         pool.allocator.alloc()                            # drain the arena
     assert not pool.ensure_decode_capacity({slot: 32}, 1)
     assert pool.slot_pages(slot) == before
+
+
+# --------------------------------------------------------------------------- #
+# paged decode: the stacked arena rides the layer scan's carry
+# --------------------------------------------------------------------------- #
+LAYOUTS = {            # block layouts a paged decode meets -> (arch, reduce)
+    "stack": ("qwen3-0.6b-toy", None),          # one attention position
+    "prefix": ("deepseek-moe-16b", {"num_layers": 3}),  # + unstacked layer
+    "groups": ("qwen3-vl-toy", None),           # 2 attention positions per
+                                                # group, cross-attn leaves
+    "hybrid": ("jamba-1.5-large-398b", {"num_layers": 16}),  # SSM on xs
+}
+
+
+def _paged_decode_steps(cfg, kv_dtype, unroll, steps=3):
+    """Greedy paged decode through ``Model.apply`` from a random arena and
+    shuffled page tables, one slot frozen: each step's logits, the tokens
+    and the final cache."""
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    pool = PagedKVPool(cfg, 3, 64, page_size=16, kv_dtype=kv_dtype,
+                       ctx_len=cfg.vision.num_image_tokens if cfg.vision
+                       else 0)
+    leaves, tree = jax.tree.flatten(pool.cache)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    cache = jax.tree.unflatten(tree, [
+        jax.random.randint(k, a.shape, -127, 128).astype(a.dtype)
+        if a.dtype == jnp.int8 else
+        jax.random.normal(k, a.shape, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a
+        for k, a in zip(keys, leaves)])
+    order = np.random.default_rng(2).permutation(3 * pool.pages_per_slot)
+    table = jnp.asarray(pool.reserved + order.reshape(3, -1), jnp.int32)
+    active = jnp.array([True, False, True])
+    step = jax.jit(lambda c, tok, pos: model.apply(
+        params, tok[:, None], mode="decode", positions=pos[:, None],
+        cache=c, page_table=table, slot_active=active, unroll_scan=unroll))
+    tok, pos = jnp.array([1, 2, 3]), jnp.array([5, 30, 47])
+    logits, toks = [], []
+    for _ in range(steps):
+        out = step(cache, tok, pos)
+        cache = {**out.cache, "page_table": table}
+        logits.append(out.logits)
+        tok = jnp.where(active, jnp.argmax(out.logits[:, 0], -1), tok)
+        toks.append(tok)
+        pos = pos + active
+    return logits, toks, cache
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_paged_decode_scanned_matches_unrolled(layout, kv_dtype):
+    """The layer scan carries the stacked arena and writes each layer's
+    cells in place; the unrolled stack (the dry-run's lowering) writes the
+    same cells at the same layer indices.  Tokens, logits and every cache
+    leaf agree bit for bit, and the active slots' cells were written."""
+    arch, reduce = LAYOUTS[layout]
+    cfg = get_config(arch)
+    cfg = cfg if reduce is None else cfg.reduced(**reduce)
+    scanned = _paged_decode_steps(cfg, kv_dtype, unroll=False)
+    unrolled = _paged_decode_steps(cfg, kv_dtype, unroll=True)
+    for a, b in zip(jax.tree.leaves(scanned), jax.tree.leaves(unrolled)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    fresh = _paged_decode_steps(cfg, kv_dtype, unroll=False, steps=0)[2]
+    written = [(new["k"], old["k"]) for new, old in zip(
+        scanned[2]["prefix"] + list((scanned[2]["block"] or {}).values()),
+        fresh["prefix"] + list((fresh["block"] or {}).values()))
+        if "k" in old]
+    assert written
+    for new, old in written:
+        assert not np.array_equal(new, old)
 
 
 # --------------------------------------------------------------------------- #

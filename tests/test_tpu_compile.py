@@ -4,23 +4,30 @@ TPU compiler for a described (not attached) v5e chip at real model widths.
 Interpret mode checks what a kernel computes, not whether Mosaic accepts
 its block shapes, VMEM use or in-kernel ops; this file checks the latter
 with no chip.  Widths: qwen2-0.5b (H=14, Hkv=2, head_dim 64, bf16, cache
-512, page 16) and one head_dim-128 shape (H=16, Hkv=8).
+512, page 16) and one head_dim-128 shape (H=16, Hkv=8).  One whole program,
+the paged ``decode_block`` at Qwen2-7B attention widths, is compiled too, to
+pin what the TPU compiler does with the stacked page arena.
 
 The topology is described inside a module fixture (never at import): only
 one process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import ModelConfig
+from repro.core.engine import InferenceEngine
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas
+from repro.models import build_model
 
 WIDTHS = {                       # name -> (heads, kv heads, head_dim)
     "qwen2-0.5b": (14, 2, 64),
@@ -104,3 +111,76 @@ def test_quant_matmul_compiles(one_chip):
     w = _sds(one_chip, (896, 4864), jnp.int8)
     s = _sds(one_chip, (4864,), jnp.float32)
     _compile(quant_matmul_pallas, x, w, s)
+
+
+def _instructions(hlo: str):
+    """(computation, name, shape, opcode, line) of every instruction of an
+    HLO module's text, and the names of the fused computations."""
+    out, fused, comp = [], set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        ins = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(", line)
+        if ins:
+            out.append((comp,) + ins.groups() + (line,))
+            fused.update(re.findall(r"calls=%([\w.-]+)", line)
+                         if ins.group(3) == "fusion" else ())
+    return out, fused
+
+
+def test_paged_decode_block_writes_the_arena_in_place(one_chip, monkeypatch):
+    """The paged ``decode_block`` at Qwen2-7B attention widths (28 / 4 heads,
+    head_dim 128, pages of 128), compiled for a v5e with the Pallas kernels
+    selected as on the chip.  The stacked arena ``[L, N, ps, Hkv, D]`` is
+    only ever written by the in-place cell scatters: no copy, no restacking
+    dynamic-update-slice, no fresh buffer.  A whole layer's arena is only
+    the slice each kernel operand reads, consumed by the kernel wrapper's
+    relayout to ``[N, ps, Hkv * D]`` (which the compiler does not fuse it
+    into).  Temporaries stay below one arena: four layers of the benchmark's
+    514 pages, so that a restacked arena alone would fill one and the
+    per-layer slices and relayouts (and a relayout of one stacked
+    ``[L, 3584, 3584]`` weight) do not."""
+    cfg = ModelConfig(name="qwen2-7b-attn", family="dense", num_layers=4,
+                      d_model=3584, num_heads=28, num_kv_heads=4,
+                      head_dim=128, d_ff=512, vocab_size=1024, qkv_bias=True,
+                      dtype="bfloat16")
+    params = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
+    eng = InferenceEngine(cfg, params=params, max_batch=8, cache_len=1024,
+                          kv_layout="paged", kv_page_size=128,
+                          kv_num_pages=514)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    args = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (params, eng.pool.cache, eng.state))
+    compiled = eng._decode_block_fn.lower(
+        *args, num_steps=2, want_logprobs=False).compile()
+    arena = eng.pool.cache["block"]["pos0"]
+    lay, n, ps, hkv, d = arena["k"].shape
+    stacked = f"bf16[{lay},{n},{ps},{hkv},{d}]"
+    per_layer = (f"bf16[{n},{ps},{hkv},{d}]", f"bf16[1,{n},{ps},{hkv},{d}]")
+    relayout = re.compile(rf"= bf16\[{n},{ps},{hkv * d}\]\S* reshape\(")
+
+    hlo = compiled.as_text()
+    ins, fused = _instructions(hlo)
+    roots = {c: op for c, _, _, op, line in ins if "ROOT" in line}
+    slices = 0
+    for comp, name, shape, op, line in ins:
+        if comp in fused:
+            continue
+        kind = (roots[re.search(r"calls=%([\w.-]+)", line).group(1)]
+                if op == "fusion" else op)
+        if shape.startswith(stacked + "{"):
+            assert op in ("parameter", "get-tuple-element", "bitcast") or \
+                kind == "scatter", line[:160]
+        elif shape.split("{")[0] in per_layer:
+            assert kind == "dynamic-slice", line[:160]
+            users = [u for u in hlo.splitlines()
+                     if re.search(rf"%{re.escape(name)}[,)]", u)
+                     and f"%{name} =" not in u]
+            assert users and all(relayout.search(u) for u in users), users
+            slices += 1
+    assert slices == len(arena)                  # one read per k / v leaf
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < sum(a.nbytes for a in arena.values()), temps
