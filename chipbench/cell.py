@@ -1,7 +1,9 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` names the cell, the
 cell names its configuration and traffic mix, and each of those (and each
 per-layer metric and correctness limit) is a file of its own under
-``chipbench/``.  Adding a cell adds files and entries; no file here changes.
+``chipbench/``, and so is each configuration's architecture
+(``families/<name>.py``).  Adding a cell adds files and entries; no file
+here changes.
 """
 from __future__ import annotations
 
@@ -52,29 +54,29 @@ def metrics_for(bench: dict, cell: str, kind: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def reader(name: str, here: Path = HERE) -> Callable[[object], Optional[float]]:
-    """``metrics/<name>.py``'s ``read`` function."""
-    path = here / "metrics" / f"{name}.py"
+def _load(path: Path, name: str):
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"chipbench_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a Qwen2 configuration file."""
-    from repro.configs import ModelConfig
-    d = conf["hidden_size"]
-    h = conf["num_attention_heads"]
-    return ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=d, num_heads=h,
-        num_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        head_dim=conf.get("head_dim") or d // h,
-        rope_theta=float(conf["rope_theta"]),
-        qkv_bias=bool(conf["attention_bias"]),
-        rms_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        source=conf["source"], dtype=conf["torch_dtype"])
+def reader(name: str, here: Path = HERE) -> Callable[[object], Optional[float]]:
+    """``metrics/<name>.py``'s ``read`` function."""
+    return _load(here / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+def family(conf: dict, here: Path = HERE):
+    """The module ``families/<name>.py`` of the configuration's
+    architecture: ``<name>`` is ``architectures[0]`` without its
+    ``ForCausalLM`` suffix, lowercased (``Qwen2ForCausalLM``: ``qwen2``).
+    It gives the program's model config, the served weights, the float32
+    reference and the counts (``families/qwen2.py`` says what each is)."""
+    arch = conf["architectures"][0]
+    name = arch.removesuffix("ForCausalLM").lower()
+    path = here / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family for architecture {arch!r}: "
+                                f"{path} does not exist")
+    return _load(path, f"family_{name}")

@@ -1,12 +1,11 @@
 """whole decode step (core/engine.py decode_block): model FLOP/s
 utilisation of the decode programs.
 
-Operations the decoded tokens need (every layer's matrices, attention over
-each token's live context, the output head), over the device time of the
-decode-block runs that decoded them, over the chip's bf16 peak.  Moves
-``itl_p95_ms``."""
+Operations the decoded tokens need (the family's ``decode_token_flops``:
+every layer's matrices, attention over each token's live context, the output
+head), over the device time of the decode-block runs that decoded them, over
+the chip's bf16 peak.  Moves ``itl_p95_ms``."""
 
-from chipbench import flops
 from chipbench.probe import DECODE
 
 PROGRAMS = ("jit_decode_block",)
@@ -20,7 +19,8 @@ def read(run):
         for step in range(k):
             for pos, left in live:
                 if step < left:
-                    ops += flops.decode_token_flops(run.dims, pos + 1 + step)
+                    ops += run.family.decode_token_flops(run.dims,
+                                                         pos + 1 + step)
     if not ops:
         return None
     device_s = sum(e - s for _, s, e in runs) / 1e9

@@ -1,11 +1,12 @@
 """kernels (kernels/paged_attention.py): the paged-attention kernel's share
 of its roofline.
 
-For each decode-block run in the trace, each of its steps and each layer,
-the least time of one kernel call (the larger of its operations over peak
-and its bytes over HBM bandwidth, for the live context each slot attends),
-summed, over the device time of the kernel's operations inside those runs.
-Moves ``itl_p95_ms``."""
+For each decode-block run in the trace and each of its steps, the least time
+of the step's kernel calls (the larger of their operations over peak and
+their bytes over HBM bandwidth, for the live context each slot attends in
+each layer: the family's whole-stack ``paged_attention_cost``), summed, over
+the device time of the kernel's operations inside those runs.  Moves
+``itl_p95_ms``."""
 
 from chipbench import flops
 from chipbench import trace as T
@@ -24,9 +25,9 @@ def read(run):
             ctx = [pos + 1 + step for pos, left in live if step < left]
             if ctx:
                 least += flops.least_time_s(
-                    *flops.paged_attention_cost(run.dims, ctx), run.peaks)
+                    *run.family.paged_attention_cost(run.dims, ctx),
+                    run.peaks)
         kernel_ns += T.ops_within(run.trace, KERNEL, s, e)
     if not least or not kernel_ns:
         return None
-    least *= run.dims["num_hidden_layers"]
     return 100.0 * least / (kernel_ns / 1e9)

@@ -1,30 +1,27 @@
-"""Plain float32 Qwen2 forward pass: the reference that decides ``correct``.
+"""What every family's plain float32 reference shares, and the comparison
+of its logits with the tokens the program served, which decides ``correct``.
 
-Written from the published description (arXiv:2407.10671; the Hugging Face
-``Qwen2`` modelling code): pre-norm decoder layers of RMSNorm, grouped-query
-self-attention with q/k/v biases and rotary embedding (rotate-half form,
-base ``rope_theta``), SwiGLU MLP, final RMSNorm and the output head (the
-embedding table when tied).  It imports nothing of the program and takes
-nothing the program made: the weights come again from the seed, one layer
-at a time (``weights.layer_weights``), so the whole model never has to sit
-on the device in float32.  Matrix products run at ``highest`` precision.
+A family's ``Reference`` (``families/<name>.py``) is written from the
+architecture's published description.  It imports nothing of the program
+and takes nothing the program made: the weights come again from the seed,
+one layer at a time, so the whole model never has to sit on the device in
+float32.  Matrix products run at ``highest`` precision, and sequences pad to
+a power of two from ``MIN_PAD`` so few shapes compile.
 
 ``quant`` makes the control: the same pass with every linear layer's
-weights (per output channel) and inputs (per token) rounded to float8 e4m3,
-the precision below the served bfloat16.  The control is put
-in the program's place: ``greedy_tokens`` and ``nucleus_tokens`` give what
-it would serve, and the same comparison judges it.
+weights (per output channel) and inputs (per token) rounded by a function of
+``ROUNDERS``; ``fp8`` is float8 e4m3, the precision below the served
+bfloat16.  The control is put in the program's place: ``greedy_tokens`` and
+``nucleus_tokens`` give what it would serve, and the same comparison judges
+it.
 """
 from __future__ import annotations
 
-import math
-from typing import List, Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from chipbench import weights as W
 
 MIN_PAD = 256      # sequences pad to a power of two from here: few shapes
 
@@ -36,101 +33,6 @@ def _round_fp8(x, axis):
 
 
 ROUNDERS = {"fp8": _round_fp8}
-
-
-class Reference:
-    """Qwen2 forward for one configuration (``dims``: the config file)."""
-
-    def __init__(self, dims: dict, quant: Optional[str] = None):
-        self.dims = dims
-        self.quant = quant
-        d = dims["hidden_size"]
-        self.h = dims["num_attention_heads"]
-        self.hkv = dims["num_key_value_heads"]
-        self.hd = dims.get("head_dim") or d // self.h
-        self.eps = float(dims["rms_norm_eps"])
-        self.theta = float(dims["rope_theta"])
-        self._layer = jax.jit(self._layer_fn)
-        self._embed = jax.jit(lambda table, toks: table[toks].astype(
-            jnp.float32))
-        self._head = jax.jit(self._head_fn)
-        self._gen_layer = jax.jit(lambda key: jax.tree.map(
-            lambda a: a.astype(jnp.float32),
-            W.layer_weights(key, dims, jnp.dtype(dims["torch_dtype"]))))
-        self._gen_tables = jax.jit(lambda key: jax.tree.map(
-            lambda a: a.astype(jnp.float32),
-            W.table_weights(key, dims, jnp.dtype(dims["torch_dtype"]))))
-
-    # -- pieces -------------------------------------------------------------
-    def _mm(self, x, w):
-        """``x @ w`` for a linear layer; the control rounds both sides."""
-        if self.quant is not None:
-            rnd = ROUNDERS[self.quant]
-            x, w = rnd(x, -1), rnd(w, 0)
-        return x @ w
-
-    def _rms(self, x, w):
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                 + self.eps) * w
-
-    def _rope(self, x, pos):                       # x [T, heads, hd]
-        half = self.hd // 2
-        inv = 1.0 / (self.theta ** (jnp.arange(half, dtype=jnp.float32)
-                                    * 2.0 / self.hd))
-        ang = pos[:, None].astype(jnp.float32) * inv[None, :]
-        cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-    def _layer_fn(self, x, p):                     # x [T, D] float32
-        t = x.shape[0]
-        a = p["attn"]
-        h = self._rms(x, a["ln"])
-        q = (self._mm(h, a["wq"]) + a["bq"]).reshape(t, self.h, self.hd)
-        k = (self._mm(h, a["wk"]) + a["bk"]).reshape(t, self.hkv, self.hd)
-        v = (self._mm(h, a["wv"]) + a["bv"]).reshape(t, self.hkv, self.hd)
-        pos = jnp.arange(t)
-        q, k = self._rope(q, pos), self._rope(k, pos)
-        g = self.h // self.hkv
-        q = q.reshape(t, self.hkv, g, self.hd)
-        s = jnp.einsum("qhgd,khd->hgqk", q, k) / math.sqrt(self.hd)
-        causal = pos[:, None] >= pos[None, :]
-        s = jnp.where(causal, s, -jnp.inf)
-        o = jnp.einsum("hgqk,khd->qhgd", jax.nn.softmax(s, axis=-1), v)
-        x = x + self._mm(o.reshape(t, self.h * self.hd), a["wo"])
-        h = self._rms(x, p["ffn_ln"])
-        f = p["ffn"]
-        u = jax.nn.silu(self._mm(h, f["w_gate"])) * self._mm(h, f["w_up"])
-        return x + self._mm(u, f["w_down"])
-
-    def _head_fn(self, x, rows, final_ln, head):
-        return self._mm(self._rms(x[rows], final_ln), head)
-
-    # -- the pass -----------------------------------------------------------
-    def logits(self, seed: int, seqs: Sequence[Tuple[List[int], int]]
-               ) -> List[jax.Array]:
-        """For each (tokens, n): the logits [n, vocab] that predict the last
-        ``n`` tokens of ``tokens``, from one causal pass over ``tokens``."""
-        base = W.seed_key(seed, W.WEIGHTS)
-        with jax.default_matmul_precision("highest"):
-            tables = self._gen_tables(jax.random.fold_in(base, 0))
-            xs = []
-            for toks, _ in seqs:
-                padded = np.zeros(max(MIN_PAD, 1 << (len(toks) - 1)
-                                      .bit_length()), np.int32)
-                padded[:len(toks)] = toks
-                xs.append(self._embed(tables["embed"], jnp.asarray(padded)))
-            for i in range(self.dims["num_hidden_layers"]):
-                p = self._gen_layer(W.layer_key(base, i))
-                xs = [self._layer(x, p) for x in xs]
-                del p
-            head = (tables["embed"].T if self.dims["tie_word_embeddings"]
-                    else tables["lm_head"])
-            out = []
-            for x, (toks, n) in zip(xs, seqs):
-                rows = jnp.arange(len(toks) - 1 - n, len(toks) - 1)
-                out.append(self._head(x, rows, tables["final_ln"], head))
-            return out
 
 
 def served_gaps(ref_logits: jax.Array, served: Sequence[int]) -> np.ndarray:

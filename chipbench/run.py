@@ -11,7 +11,8 @@ reaches.  The load then ramps for the mix's ``ramp_s`` and is measured for
 end-to-end metrics; with ``--trace 1`` a profiler trace of part of the
 window gives its per-layer metrics.  After the window the program is freed
 and a sample of the finished requests is checked against the plain float32
-reference (``reference.py``); ``correct`` says whether every compared
+reference of the configuration's family (``families/<name>.py``, with
+``reference.py``); ``correct`` says whether every compared
 number kept its limit (``limits/<cell>.json``), failed and refused requests
 among them.
 
@@ -112,7 +113,9 @@ class CompileLog:
 
 @dataclass
 class RunData:
-    """What the per-layer readers (``metrics/<name>.py``) read."""
+    """What the per-layer readers (``metrics/<name>.py``) read; ``family``
+    is the configuration's ``families/<name>.py`` module, whose counts the
+    readers divide by."""
     dims: dict
     peaks: dict
     recs: list
@@ -123,6 +126,7 @@ class RunData:
     probe: object = None
     busy_s: float = 0.0         # device busy time in the trace, per chip
     window_s: float = 0.0       # first to last event of the trace
+    family: object = None
 
     def counter_delta(self, key: str) -> int:
         return self.counters["close"][key] - self.counters["open"][key]
@@ -195,24 +199,24 @@ def pick_sample(recs, seed: int, greedy: bool) -> list:
     return sample
 
 
-def compare(dims: dict, seed: int, greedy: list, sampled: list,
+def compare(family, dims: dict, seed: int, greedy: list, sampled: list,
             sampling: dict, control: Optional[str] = None
             ) -> Tuple[dict, Optional[dict]]:
     """The numbers that decide ``correct``, for the tokens the program
-    served: ``logit_gap``, the widest gap of a served greedy token below
-    the reference's best logit, and ``nucleus_excess``, the furthest a
-    served sampled token lies outside the reference's top-p nucleus
-    (``reference.nucleus_excess``).  With ``control`` (a precision of
-    ``reference.ROUNDERS``) the same numbers come second for the control put
-    in the program's place: the tokens it serves at the same positions (its
-    best token for greedy requests, a draw from its nucleus for sampled
-    ones)."""
+    served, against ``family.Reference``: ``logit_gap``, the widest gap of
+    a served greedy token below the reference's best logit, and
+    ``nucleus_excess``, the furthest a served sampled token lies outside
+    the reference's top-p nucleus (``reference.nucleus_excess``).  With
+    ``control`` (a precision of ``reference.ROUNDERS``) the same numbers
+    come second for the control put in the program's place: the tokens it
+    serves at the same positions (its best token for greedy requests, a
+    draw from its nucleus for sampled ones)."""
     import numpy as np
     from chipbench import reference
     reqs = greedy + sampled
     n = len(greedy)
     seqs = [(r.prompt + r.tokens, len(r.tokens)) for r in reqs]
-    ref = reference.Reference(dims).logits(seed, seqs)
+    ref = family.Reference(dims).logits(seed, seqs)
     temp, top_p = (float(sampling.get("temperature", 1.0)),
                    float(sampling.get("top_p", 1.0)))
 
@@ -233,7 +237,7 @@ def compare(dims: dict, seed: int, greedy: list, sampled: list,
     program = numbers([r.tokens for r in reqs])
     if not control:
         return program, None
-    low = reference.Reference(dims, quant=control).logits(seed, seqs)
+    low = family.Reference(dims, quant=control).logits(seed, seqs)
     served = [reference.greedy_tokens(lg) for lg in low[:n]]
     for i, lg in enumerate(low[n:]):
         u = np.random.default_rng([int(seed), 4, i]).random(lg.shape[0])
@@ -281,17 +285,19 @@ def run_cell(wl: dict, conf: dict, mix: dict, limits: dict, metrics: list,
                else jax.devices()[:wl["chips"]])
     dev = devices[0]
     from chipbench import peaks as P
-    from chipbench import replica, weights
+    from chipbench import replica
     from chipbench.load import Load
     from chipbench.traffic import Traffic
     peaks = P.peaks_for(dev.device_kind) if require_chip else {}
     log(f"[device] {dev.platform} {dev.device_kind} x{len(devices)}; jax "
         f"{jax.__version__}; compile cache {cache_dir}")
 
+    family = C.family(conf)
     clog = CompileLog()
     t = time.perf_counter()
-    params = weights.engine_params(seed, conf)
-    client = replica.build(C.model_config(conf), conf["serve_flags"], params)
+    params = family.engine_params(seed, conf)
+    client = replica.build(family.model_config(conf), conf["serve_flags"],
+                           params)
     del params
     log(f"[setup] weights and replica {time.perf_counter() - t:.3f} s, "
         f"process start to here {time.perf_counter() - T_START:.3f} s")
@@ -377,7 +383,8 @@ def run_cell(wl: dict, conf: dict, mix: dict, limits: dict, metrics: list,
               "count": len(devices), "memory_peak_bytes": peak_bytes}
     result = {"correct": False, "attempted": len(attempted),
               "failed": len(failed), "metrics": {}, "device": device}
-    run = RunData(conf, peaks, recs, opens, closes, counters, probe=probe)
+    run = RunData(conf, peaks, recs, opens, closes, counters, probe=probe,
+                  family=family)
     if trace:
         from chipbench import trace as T
         run.trace = T.reduce(T.find_xplane(trace_dir))
@@ -415,7 +422,7 @@ def run_cell(wl: dict, conf: dict, mix: dict, limits: dict, metrics: list,
                "sampled_tokens": sum(len(r.tokens) for r in sampled),
                "failed_requests": len(failed)}
     t_ref = time.perf_counter()
-    program, stand_in = (compare(conf, seed, greedy, sampled,
+    program, stand_in = (compare(family, conf, seed, greedy, sampled,
                                  mix.get("sampling", {}), control)
                          if greedy or sampled else ({}, {}))
     log(f"[check] reference over {len(greedy)} greedy and {len(sampled)} "

@@ -1,15 +1,16 @@
 """The comparison that decides ``correct``, rehearsed on the CPU at a tiny
-size: the served path agrees with the plain float32 reference, the control
-(the reference in a lower precision, put in the program's place) fails
-the comparison, a token altered where it is produced fails it, a request
-that fails in the engine fails it, and the measured path refuses to run
-without a TPU."""
+size: the served path agrees with the plain float32 reference, also for an
+architecture that exists only as a family file, the control (the reference
+in a lower precision, put in the program's place) fails the comparison, a
+token altered where it is produced fails it, a request that fails in the
+engine fails it, and the measured path refuses to run without a TPU."""
 import json
 import os
 import shutil
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import pytest
@@ -17,14 +18,19 @@ import pytest
 from chipbench import cell as C
 from chipbench import replica
 from chipbench import run as R
+from chipbench.traffic import Traffic
 from repro.core.faults import FaultInjector
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2**31 + 12345
 # at the tiny size, on three seeds: served greedy tokens sit 0 to 0.019
 # below the reference's best, the fp8 control's 0.33 to 0.35; served
-# sampled tokens lie 0 to 0.0001 outside the reference's nucleus, the fp8
-# control's 0.007 to 0.013
+# sampled tokens lie 0 to 0.0001 outside the reference's nucleus.  The fp8
+# control serves a token outside the nucleus about once in 200 sampled
+# tokens, so its reading needs a sample of fixed size (``fixed_sample``):
+# over 1,000 greedy and 1,000 sampled tokens, on five seeds, 0.44 to 0.62
+# and 0.0074 to 0.0148; over 400 of each, two of five seeds read 0 and
+# 0.0005 outside the nucleus
 TINY_LIMITS = {"max_logit_gap": 0.15, "min_served_tokens": 100,
                "max_nucleus_excess": 0.003, "min_sampled_tokens": 100}
 
@@ -52,7 +58,7 @@ def own_compile_cache(tmp_path_factory):
     compilation_cache.reset_cache()
 
 
-def tiny(kind):
+def tiny(kind, **conf_update):
     """A Qwen2 configuration with tied embeddings (qwen2-0.5b's file) and
     a mix cut to a size the CPU runs in seconds: two layers, d 64, a
     512-token vocabulary, four slots."""
@@ -61,7 +67,8 @@ def tiny(kind):
                 num_attention_heads=4, num_key_value_heads=2,
                 num_hidden_layers=2, vocab_size=512,
                 serve_flags=["--max-batch", "4", "--cache-len", "256",
-                             "--kv-layout", "paged", "--prefill-chunk", "64"])
+                             "--kv-layout", "paged", "--prefill-chunk", "64"],
+                **conf_update)
     mix = C.traffic(kind)
     if kind == "chat_sessions":
         mix.update(session_rate_per_s=1.5, ramp_s=2,
@@ -86,8 +93,8 @@ def tiny(kind):
     return wl, conf, mix, metrics
 
 
-def tiny_run(kind, control=None):
-    wl, conf, mix, metrics = tiny(kind)
+def tiny_run(kind, control=None, **conf_update):
+    wl, conf, mix, metrics = tiny(kind, **conf_update)
     return R.run_cell(wl, conf, mix, TINY_LIMITS, metrics, SEED, 4.0, False,
                       require_chip=False, control=control,
                       log=lambda *a, **k: None)
@@ -115,14 +122,81 @@ def test_served_path_agrees_with_reference(chat):
     assert list(chat)[-1] == "checks"
 
 
+# a family that exists only as a file: Qwen2's code under a new name
+NEW_FAMILY = '''
+from chipbench import cell
+
+_qwen2 = cell.family({"architectures": ["Qwen2ForCausalLM"]}, here=cell.HERE)
+model_config = _qwen2.model_config
+engine_params = _qwen2.engine_params
+Reference = _qwen2.Reference
+total_params = _qwen2.total_params
+kv_bytes_per_token = _qwen2.kv_bytes_per_token
+decode_token_flops = _qwen2.decode_token_flops
+prefill_flops = _qwen2.prefill_flops
+paged_attention_cost = _qwen2.paged_attention_cost
+'''
+
+
+def test_family_file_alone_drives_the_served_path(tmp_path, monkeypatch):
+    """A new architecture needs a file under ``families/`` and no edit to
+    a harness file: the run finds it by ``architectures[0]``."""
+    (tmp_path / "families").mkdir()
+    (tmp_path / "families" / "tinyarch.py").write_text(NEW_FAMILY)
+    found, real_family = [], C.family
+
+    def family(conf, here=tmp_path):
+        mod = real_family(conf, here=here)
+        found.append(mod.__file__)
+        return mod
+
+    monkeypatch.setattr(C, "family", family)
+    res = tiny_run("chat_sessions", architectures=["TinyArchForCausalLM"])
+    assert str(tmp_path / "families" / "tinyarch.py") in found
+    assert res["correct"] is True
+    assert res["checks"]["served_tokens"]["value"] >= 100
+    assert res["checks"]["sampled_tokens"]["value"] >= 100
+
+
+def fixed_sample(conf, mix, tokens=1000):
+    """Greedy and sampled requests of the chat mix, each turn's prompt the
+    whole history, with fixed ids in place of the served tokens: about
+    ``tokens`` served tokens of each kind, whatever a timed window would
+    have finished."""
+    t = Traffic(mix, SEED, conf["vocab_size"], 60.0, 4)
+    reqs = {True: [], False: []}
+    for s in t.sessions:
+        history = t.system_prompt(s.system)
+        for j, turn in enumerate(s.turns):
+            prompt = history + t.user_message(s.index, j, turn.user_tokens)
+            if len(prompt) + turn.output_tokens > t.context_limit:
+                break
+            served = t.ids(turn.output_tokens, 9, s.index, j)
+            if sum(len(r.tokens) for r in reqs[s.greedy]) < tokens:
+                reqs[s.greedy].append(SimpleNamespace(prompt=prompt,
+                                                      tokens=served))
+            history = prompt + served
+    return reqs[True], reqs[False]
+
+
 def test_control_fails_the_comparison(chat):
     """The fp8 reference put in the program's place, judged by the same
-    rule, comes out not correct, on greedy and on sampled tokens alike."""
-    control = tiny_run("chat_sessions", control="fp8")
-    assert control["correct"] is False
+    rule, comes out not correct, on greedy and on sampled tokens alike:
+    in a run, and on a fixed sample, so that how much a timed window on a
+    busy CPU finished does not decide it."""
+    assert tiny_run("chat_sessions", control="fp8")["correct"] is False
+    wl, conf, mix, metrics = tiny("chat_sessions")
+    greedy, sampled = fixed_sample(conf, mix)
+    _, control = R.compare(C.family(conf), conf, SEED, greedy, sampled,
+                           mix["sampling"], control="fp8")
+    checks = R.judge(dict(control, failed_requests=0,
+                          served_tokens=sum(len(r.tokens) for r in greedy),
+                          sampled_tokens=sum(len(r.tokens) for r in sampled)),
+                     TINY_LIMITS)
+    assert not all(c["ok"] for c in checks.values())
     for name, limit in (("logit_gap", "max_logit_gap"),
                         ("nucleus_excess", "max_nucleus_excess")):
-        value = control["checks"][name]["value"]
+        value = control[name]
         assert value > TINY_LIMITS[limit]
         assert value >= 3 * chat["checks"][name]["value"]
 
