@@ -36,6 +36,31 @@ class MoEConfig:
     moe_offset: int = 0
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
+    # the experts this chip holds, ``[lo, hi)`` of ``num_experts``: set, the
+    # layer routes over every expert and computes, with no capacity and no
+    # drop, the part of the result its own experts give (one chip's share
+    # under expert parallelism; the exchange with the other chips is not
+    # part of this program)
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else self.held[1] - self.held[0]
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (arXiv:2309.00071) on the rotary embedding, as the Hugging Face
+    ``_compute_yarn_parameters`` computes it: frequencies ramp from plain
+    (``beta_fast`` rotations over the original context) to divided by
+    ``factor`` (``beta_slow`` rotations), and cos and sin are multiplied by
+    ``attention_factor``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -91,6 +116,14 @@ class ModelConfig:
     # launcher overrides this per input-shape (long_500k forces a window on
     # full-attention archs — see DESIGN.md §6).
     sliding_window: int = 0
+    # per-layer attention windows, repeated over the depth: () = every layer
+    # uses ``sliding_window``; (1024, 1024, 1024, 0) = three layers that see
+    # the last 1,024 positions, then one that sees the whole context.  A
+    # windowed layer's kind carries its window (``"moe@1024"``).
+    layer_windows: Tuple[int, ...] = ()
+    # YaRN on the rotation of the layers that carry no window of their own
+    # (the windowed layers of ``layer_windows`` keep the plain rotation)
+    rope_scaling: Optional[RopeScaling] = None
     # hybrid: one attention layer per ``attn_every`` layers, rest are SSM.
     attn_every: int = 0
     moe: Optional[MoEConfig] = None
@@ -123,6 +156,8 @@ class ModelConfig:
 
         kinds: 'attn' (self-attn + ffn), 'moe' (self-attn + moe-ffn),
                'ssm' (mamba block), 'ssm_moe', 'xattn' (cross-attn + ffn).
+        An attention layer with a window of its own (``layer_windows``)
+        carries it after ``@``: 'moe@1024' (see :func:`split_kind`).
         """
         kinds = []
         for i in range(self.num_layers):
@@ -142,6 +177,10 @@ class ModelConfig:
                            and i % self.moe.moe_every == self.moe.moe_offset)
                 if use_moe:
                     base = {"attn": "moe", "ssm": "ssm_moe"}.get(base, base + "_moe")
+            if self.layer_windows and base in ("attn", "moe"):
+                w = self.layer_windows[i % len(self.layer_windows)]
+                if w:
+                    base = f"{base}@{w}"
             kinds.append(base)
         return tuple(kinds)
 
@@ -201,6 +240,13 @@ class ModelConfig:
         return ModelConfig(**kw)
 
 
+def split_kind(kind: str) -> Tuple[str, int]:
+    """``"moe@1024"`` -> ``("moe", 1024)``; a kind without a window of its
+    own -> ``(kind, 0)`` (it attends by the model-wide ``sliding_window``)."""
+    base, _, window = kind.partition("@")
+    return base, int(window or 0)
+
+
 def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     d = cfg.d_model
     total = cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2)
@@ -217,19 +263,20 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         ssm_p = d * (2 * d_in + d_bc + nheads) + (d_in + d_bc) * cfg.ssm.conv_width \
             + d_in * d + 2 * nheads
     for kind in kinds:
+        kind = split_kind(kind)[0]
         if kind in ("attn", "xattn"):
             total += attn + ffn
         elif kind == "moe":
             m = cfg.moe
             e_ff = m.expert_d_ff or cfg.d_ff
-            n_e = (m.experts_per_token if active_only else m.num_experts)
+            n_e = (m.experts_per_token if active_only else m.num_held)
             total += attn + 3 * d * e_ff * (n_e + m.num_shared_experts) + d * m.num_experts
         elif kind == "ssm":
             total += ssm_p + ffn
         elif kind == "ssm_moe":
             m = cfg.moe
             e_ff = m.expert_d_ff or cfg.d_ff
-            n_e = (m.experts_per_token if active_only else m.num_experts)
+            n_e = (m.experts_per_token if active_only else m.num_held)
             total += ssm_p + 3 * d * e_ff * (n_e + m.num_shared_experts) + d * m.num_experts
     if cfg.audio is not None:  # encoder stack
         total += cfg.audio.encoder_layers * (attn + ffn)

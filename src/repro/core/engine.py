@@ -411,8 +411,10 @@ class InferenceEngine:
         # requests derive their base key from the seed alone, unseeded ones
         # draw from this engine-owned chain at add_request (deterministic
         # for a fixed engine seed + submission order).
+        self._moe_counts = cfg.moe is not None and cfg.moe.held is not None
         self.state = self._on_device(init_decode_state(
-            max_batch, self.ctx_len, max_stop_tokens, spec_k=self.spec_k))
+            max_batch, self.ctx_len, max_stop_tokens, spec_k=self.spec_k,
+            moe_counts=self._moe_counts))
         self._request_rng = jax.random.PRNGKey(seed + 1)
         self._streamers: Dict[int, TokenStreamDecoder] = {}
         # per-request stop-sequence checkers (only for requests that set
@@ -539,6 +541,8 @@ class InferenceEngine:
                     ctx_valid=st.ctx_valid if use_ctx else None,
                     page_table=cache["page_table"] if paged else None,
                     slot_active=st.active if paged else None)
+                if out.moe_counts is not None:
+                    st = st._replace(moe_counts=st.moe_counts + out.moe_counts)
                 # frozen slots keep their previous cache bit-for-bit: the
                 # dense path repairs the written ring cell after the fact,
                 # the paged path already redirected the write to the slot's
@@ -582,6 +586,9 @@ class InferenceEngine:
                     return (cache, st), (emit, chosen, top_v, top_i)
                 return (cache, st), emit
 
+            if state.moe_counts is not None:       # counted per block
+                state = state._replace(moe_counts=jnp.zeros_like(
+                    state.moe_counts))
             (cache, state), ys = jax.lax.scan(body, (cache, state), None,
                                               length=num_steps)
             if want_logprobs:
@@ -2105,7 +2112,7 @@ class InferenceEngine:
         # (_deactivate_slot), and the donated one may already be invalid
         self.state = self._on_device(init_decode_state(
             self.pool.max_batch, self.ctx_len, self.max_stop_tokens,
-            spec_k=self.spec_k))
+            spec_k=self.spec_k, moe_counts=self._moe_counts))
         if isinstance(self._draft_source, DraftModelSource):
             # the draft pool/state may have been donated into the failed
             # round as well — rebuild both; slots re-prime at re-admission
@@ -2577,7 +2584,13 @@ class InferenceEngine:
         if block_plan is not None:
             num_steps, toks, lps = block_plan
             with tracing.span("engine.sync"):
-                block = np.asarray(toks)          # [K, B]: the block's one sync
+                if self.state.moe_counts is None:
+                    block = np.asarray(toks)      # [K, B]: the block's one sync
+                else:                             # same sync, two more ints
+                    block, (rows, experts) = jax.device_get(
+                        (toks, self.state.moe_counts))
+                    self.scheduler.stats.moe_rows += int(rows)
+                    self.scheduler.stats.moe_experts += int(experts)
                 lp_c = lp_v = lp_i = None
                 if lps is not None:
                     lp_c, lp_v, lp_i = (np.asarray(a) for a in lps)

@@ -63,10 +63,15 @@ class DecodeState(NamedTuple):
     active: jax.Array  # [B] bool — False: slot frozen/empty
     draft_tokens: jax.Array  # [B, K] int32 — staged speculative proposals
     draft_len: jax.Array  # [B] int32 — proposals staged this round (<= K)
+    # [2] int32 — over the last decode block's steps and expert layers:
+    # token-expert pairs the held experts computed, and held experts that
+    # computed any; None for models without held experts
+    moe_counts: Optional[jax.Array] = None
 
 
 def init_decode_state(
-    max_batch: int, ctx_len: int, max_stop: int, spec_k: int = 0
+    max_batch: int, ctx_len: int, max_stop: int, spec_k: int = 0,
+    moe_counts: bool = False
 ) -> DecodeState:
     return DecodeState(
         last_token=jnp.zeros((max_batch,), jnp.int32),
@@ -82,6 +87,7 @@ def init_decode_state(
         active=jnp.zeros((max_batch,), bool),
         draft_tokens=jnp.zeros((max_batch, max(spec_k, 1)), jnp.int32),
         draft_len=jnp.zeros((max_batch,), jnp.int32),
+        moe_counts=jnp.zeros((2,), jnp.int32) if moe_counts else None,
     )
 
 

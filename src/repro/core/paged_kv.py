@@ -49,7 +49,7 @@ from repro.configs import ModelConfig
 from repro.core.kv_cache import tree_bytes
 from repro.kernels.quant_matmul import quantize_kv_int8
 from repro.models.layers import ARENA_KEYS
-from repro.models.model import init_cache
+from repro.models.model import cache_shapes, init_cache
 
 
 class PagePoolExhausted(RuntimeError):
@@ -406,8 +406,11 @@ class PagedKVPool:
     def _init_arena(self, dtype):
         n, ps = self.num_pages, self.page_size
         int8 = self.kv_dtype == "int8"
-        dense = init_cache(self.cfg, self.max_batch, self.cache_len,
-                           ctx_len=self.ctx_len, dtype=dtype)
+        # the dense cache's shapes only: its K and V become the arena, and
+        # building them first would hold a second KV cache the size of the
+        # whole dense one (every slot at full length) while the arena is made
+        dense = cache_shapes(self.cfg, self.max_batch, self.cache_len,
+                             ctx_len=self.ctx_len, dtype=dtype)
 
         def to_arena(sub, stacked):
             out = {}
@@ -423,7 +426,7 @@ class PagedKVPool:
                         out[name + "_scale"] = jnp.ones(shape[:-1],
                                                         jnp.float32)
                 else:
-                    out[name] = leaf
+                    out[name] = jnp.zeros(leaf.shape, leaf.dtype)
             return out
 
         arena = {"prefix": [to_arena(sub, False) for sub in dense["prefix"]]}

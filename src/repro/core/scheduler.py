@@ -204,6 +204,13 @@ class SchedulerStats:
     # context, and of the pages one layer's paged_attention grid visits
     kv_live_pages: int = 0
     kv_grid_pages: int = 0
+    # Σ over decode steps and expert layers of the token-expert pairs the
+    # held experts computed (models whose MoE layers hold a share of the
+    # experts), read back with each block's tokens
+    moe_rows: int = 0
+    # Σ over decode steps and expert layers of the held experts that
+    # computed at least one row (each streams its weights once)
+    moe_experts: int = 0
 
     @property
     def host_syncs_per_token(self) -> float:
@@ -535,6 +542,8 @@ class ContinuousBatchingScheduler:
             "prefill_pad_tokens": s.prefill_pad_tokens,
             "kv_live_pages": s.kv_live_pages,
             "kv_grid_pages": s.kv_grid_pages,
+            "moe_rows": s.moe_rows,
+            "moe_experts": s.moe_experts,
             "aging_s": self.policy.aging_s,
             "latency_by_class": self.latency_by_class(),
         }

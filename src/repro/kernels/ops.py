@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.expert_gmm import expert_gmm_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas, quantize_int8  # noqa: F401
@@ -189,6 +190,7 @@ def paged_attention(
     *,
     k_scale: Optional[jax.Array] = None,   # [N, ps, Hkv] f32 (int8 arena)
     v_scale: Optional[jax.Array] = None,
+    window: int = 0,                # > 0: attend the last ``window`` positions
 ) -> jax.Array:
     """Decode attention through a page table (see kernels/paged_attention).
 
@@ -200,7 +202,7 @@ def paged_attention(
     if _on_tpu():
         return paged_attention_pallas(q, k_pages, v_pages, page_table,
                                       positions, k_scale=k_scale,
-                                      v_scale=v_scale)
+                                      v_scale=v_scale, window=window)
     b = q.shape[0]
     p, ps = page_table.shape[1], k_pages.shape[1]
     s = p * ps
@@ -212,9 +214,44 @@ def paged_attention(
         return rows.reshape(b, s, *pages.shape[2:])
 
     idx = jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = (idx <= positions[:, None]) | (positions[:, None] >= s)
     return _decode_jnp(q, gather(k_pages, k_scale), gather(v_pages, v_scale),
-                       valid)
+                       ring_valid(idx, positions, s, window))
+
+
+def ring_valid(idx: jax.Array, positions: jax.Array, total: int,
+               window: int = 0) -> jax.Array:
+    """[B, S] cells of a ring of ``total`` cells that a query at
+    ``positions`` [B] attends: every cell up to it (all of them once the
+    ring has wrapped), or with a window only the cells holding a position
+    greater than ``pos - window``."""
+    pos = positions[:, None]
+    if not window:
+        return (idx <= pos) | (pos >= total)
+    held = pos - jnp.mod(pos - idx, total)       # the position in each cell
+    return (held >= 0) & (held > pos - window)
+
+
+# --------------------------------------------------------------------------- #
+# held experts' grouped MLP
+# --------------------------------------------------------------------------- #
+def expert_gmm(
+    x: jax.Array,                   # [M, D] rows sorted by expert
+    w_gate: jax.Array,              # [E, D, F]
+    w_up: jax.Array,                # [E, D, F]
+    w_down: jax.Array,              # [E, F, D]
+    group_sizes: jax.Array,         # [E] int32, sum <= M
+) -> jax.Array:
+    """Each grouped row's expert SwiGLU MLP, [M, D] in ``x``'s dtype; the
+    rows past the groups are undefined (see kernels/expert_gmm).  The CPU
+    path is XLA's ragged dot, which computes only the grouped rows too."""
+    if _on_tpu():
+        return expert_gmm_pallas(x, w_gate, w_up, w_down, group_sizes)
+    f32 = jnp.float32
+    g = jax.lax.ragged_dot(x, w_gate, group_sizes, preferred_element_type=f32)
+    u = jax.lax.ragged_dot(x, w_up, group_sizes, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(h, w_down, group_sizes,
+                              preferred_element_type=f32).astype(x.dtype)
 
 
 # --------------------------------------------------------------------------- #

@@ -17,6 +17,13 @@ the block's last two dimensions meet the TPU tiling rule.  Cell validity is
 computed in-kernel from the query position (ring semantics: a fully wrapped
 cache attends to every cell), so no [B, S] mask array is materialised.
 
+A windowed layer (static ``window`` > 0) attends the positions greater than
+``pos - window``.  Pages wholly before the window are neither fetched nor
+computed: the index map clamps those steps to the first page in the window,
+so the pipeline sees one block index and issues no copy, and ``pl.when``
+skips their compute.  ``window=0`` (full attention) traces the kernel as it
+was before windows existed.
+
 Int8 arenas add per-(position, kv-head) scale operands ``[N, ps, Hkv]``
 (block ``(1, ps, Hkv)``); pages are dequantised in-register after the VMEM
 load (bandwidth is spent on int8 bytes, the matmul runs in f32).
@@ -43,8 +50,14 @@ def grid(batch: int, pages_per_slot: int) -> tuple:
     return (batch, pages_per_slot)
 
 
+def first_page(pos, window: int, ps: int, total: int):
+    """The first page a windowed query at ``pos`` attends (0 once the ring
+    has wrapped: every page may then hold a position in the window)."""
+    return jnp.where(pos >= total, 0, jnp.maximum(pos - window + 1, 0) // ps)
+
+
 def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
-                  num_pages, ps, hkv, d, int8):
+                  num_pages, ps, hkv, d, int8, window):
     if int8:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -62,8 +75,34 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
     pos = pos_ref[b_]
     total = num_pages * ps
     idx = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    live = (idx <= pos) | (pos >= total)                   # [1, ps] bool
+    attend = functools.partial(_attend_page, q_ref, k_ref, v_ref, rest,
+                               scale=scale, hkv=hkv, d=d, int8=int8)
+    if window:
+        # the position each cell holds: the latest one <= pos on its ring cell
+        r = jax.lax.rem(pos, total)
+        at = idx + (pos - r) - jnp.where(idx > r, total, 0)
+        live = (at >= 0) & (at > pos - window)             # [1, ps] bool
 
+        @pl.when(ip >= first_page(pos, window, ps, total))
+        def _attend():
+            attend(live)
+    else:
+        attend((idx <= pos) | (pos >= total))              # [1, ps] bool
+
+    @pl.when(ip == num_pages - 1)
+    def _finish():
+        for h in range(hkv):
+            l = jnp.maximum(l_ref[h][:, :1], 1e-30)
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+
+
+def _attend_page(q_ref, k_ref, v_ref, rest, live, *, scale, hkv, d,
+                 int8):
+    """Fold one page into every KV head's running (m, l, acc)."""
+    if int8:
+        ks_ref, vs_ref, _, acc_ref, m_ref, l_ref = rest
+    else:
+        _, acc_ref, m_ref, l_ref = rest
     for h in range(hkv):
         q = q_ref[0, h].astype(jnp.float32)                # [G, D]
         k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)   # [ps, D]
@@ -87,14 +126,8 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
         m_ref[h] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
         l_ref[h] = jnp.broadcast_to(l_cur, l_ref.shape[1:])
 
-    @pl.when(ip == num_pages - 1)
-    def _finish():
-        for h in range(hkv):
-            l = jnp.maximum(l_ref[h][:, :1], 1e-30)
-            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention_pallas(
     q: jax.Array,                   # [B, H, D]
     k_pages: jax.Array,             # [N, ps, Hkv, D] page arena
@@ -104,6 +137,7 @@ def paged_attention_pallas(
     *,
     k_scale: jax.Array | None = None,   # [N, ps, Hkv] f32 (int8 arena)
     v_scale: jax.Array | None = None,
+    window: int = 0,                # > 0: attend the last ``window`` positions
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
@@ -116,6 +150,9 @@ def paged_attention_pallas(
     # index maps see (grid idxs..., *scalar_prefetch_refs); the page hop is
     # pt[b_, ip] — the whole point of the kernel
     def kv_map(b_, ip, pt, pos):
+        if window:      # steps before the window repeat its first page: the
+            # pipeline sees no new block and copies nothing
+            ip = jnp.maximum(ip, first_page(pos[b_], window, ps, p * ps))
         return (pt[b_, ip], 0, 0)
 
     def q_map(b_, ip, pt, pos):
@@ -134,7 +171,8 @@ def paged_attention_pallas(
         operands += [k_scale, v_scale]
 
     kernel = functools.partial(_paged_kernel, scale=1.0 / (d ** 0.5),
-                               num_pages=p, ps=ps, hkv=hkv, d=d, int8=int8)
+                               num_pages=p, ps=ps, hkv=hkv, d=d, int8=int8,
+                               window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid(b, p),

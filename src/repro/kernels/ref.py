@@ -133,12 +133,14 @@ def paged_attention_ref(
     *,
     k_scale: jax.Array | None = None,   # [N, ps, Hkv] f32 (int8 arenas)
     v_scale: jax.Array | None = None,
+    window: int = 0,                # > 0: only the last ``window`` positions
 ) -> jax.Array:
     """Decode attention through a page table: gather each sequence's pages
     into a contiguous [B, P*ps, Hkv, D] view (dequantising int8 pages with
     their per-(position, head) scales), mask cells past the query position
-    (ring semantics: a fully wrapped cache attends to everything), and run
-    the dense decode oracle."""
+    (ring semantics: a fully wrapped cache attends to everything) or, with a
+    window, cells whose position is ``pos - window`` or earlier, and run the
+    dense decode oracle."""
     b = q.shape[0]
     p = page_table.shape[1]
     ps = k_pages.shape[1]
@@ -153,5 +155,33 @@ def paged_attention_ref(
     k = gather(k_pages, k_scale)
     v = gather(v_pages, v_scale)
     idx = jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = (idx <= positions[:, None]) | (positions[:, None] >= s)
+    pos = positions[:, None]
+    if window:
+        held = pos - jnp.mod(pos - idx, s)       # the position in each cell
+        valid = (held >= 0) & (held > pos - window)
+    else:
+        valid = (idx <= pos) | (pos >= s)
     return decode_attention_ref(q, k, v, valid)
+
+
+def expert_gmm_ref(
+    x: jax.Array,                   # [M, D] rows sorted by expert
+    w_gate: jax.Array,              # [E, D, F]
+    w_up: jax.Array,                # [E, D, F]
+    w_down: jax.Array,              # [E, F, D]
+    group_sizes: jax.Array,         # [E] int32, sum <= M
+) -> jax.Array:
+    """Every expert's SwiGLU MLP on every row (a dense einsum), each row
+    keeping its own group's output: rows ``[start_e, end_e)`` belong to
+    expert ``e``, rows past the last group get zero.  [M, D] float32; the
+    hidden activation is rounded to ``x``'s dtype, as the kernel rounds it
+    before the down projection."""
+    ends = jnp.cumsum(group_sizes)
+    rows = jnp.arange(x.shape[0])[None, :]
+    mine = (rows >= (ends - group_sizes)[:, None]) & (rows < ends[:, None])
+    xf = x.astype(jnp.float32)
+    g = jnp.einsum("md,edf->emf", xf, w_gate.astype(jnp.float32))
+    u = jnp.einsum("md,edf->emf", xf, w_up.astype(jnp.float32))
+    h = (jax.nn.silu(g) * u).astype(x.dtype).astype(jnp.float32)
+    out = jnp.einsum("emf,efd->emd", h, w_down.astype(jnp.float32))
+    return jnp.sum(jnp.where(mine[..., None], out, 0.0), axis=0)

@@ -20,9 +20,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import tracing
-from repro.configs import ModelConfig
+from repro.configs import ModelConfig, RopeScaling, split_kind
 from repro.distributed import constrain
 from repro.kernels import ops
 from repro.kernels.quant_matmul import quantize_kv_int8
@@ -55,13 +56,46 @@ def rmsnorm_gated(x: jax.Array, z: jax.Array, w: jax.Array,
     return rmsnorm(x * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype), w, eps)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D]; positions: [B, S] (per-token absolute positions)."""
+def yarn_frequencies(head_dim: int, theta: float,
+                     scaling: RopeScaling) -> np.ndarray:
+    """YaRN's rotation frequencies (Hugging Face ``_compute_yarn_parameters``,
+    ``truncate`` on): dimensions that turn more than ``beta_fast`` times over
+    the original context keep theirs, those under ``beta_slow`` times are
+    divided by ``factor``, with a linear ramp between.  Constants: computed
+    once when the program is traced."""
+    half = head_dim // 2
+    plain = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim)
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    return (plain / scaling.factor * ramp + plain * (1.0 - ramp)
+            ).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[RopeScaling] = None) -> jax.Array:
+    """x: [B, S, H, D]; positions: [B, S] (per-token absolute positions).
+    ``scaling``: YaRN frequencies, cos and sin times its attention factor."""
     b, s, h, d = x.shape
     half = d // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_frequencies(d, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs                  # [B,S,half]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]     # [B,S,1,half]
+    if scaling is not None:
+        cos, sin = cos * scaling.attention_factor, sin * scaling.attention_factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1).astype(x.dtype)
@@ -138,12 +172,13 @@ def apply_self_attn(
     layer: Optional[jax.Array] = None,  # paged decode: the arena leaves are
                                      # stacked [L, N, ...] and this is the
                                      # layer's index into them
+    rope_scaling: Optional[RopeScaling] = None,
 ) -> Tuple[jax.Array, Optional[Params]]:
     b, s, _ = x.shape
     h = rmsnorm(x, p["ln"], cfg.rms_eps)
     q, k, v = _qkv(p, h, h, cfg)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta, rope_scaling)
 
     if mode == "prefill" and resume:
         # continuation after a prefix-cache hit or an earlier prefill chunk:
@@ -226,7 +261,8 @@ def apply_self_attn(
             with tracing.scope("attention_kernel"):
                 outs.append(ops.paged_attention(
                     q[:, j], at_layer(kc), at_layer(vc), page_table, pos_j,
-                    k_scale=at_layer(ksc), v_scale=at_layer(vsc)))
+                    k_scale=at_layer(ksc), v_scale=at_layer(vsc),
+                    window=window))
         out = jnp.stack(outs, axis=1)                               # [B,S,H,hd]
         new_cache = ({"k": kc, "v": vc} if ksc is None else
                      {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})
@@ -261,7 +297,7 @@ def apply_self_attn(
         outs = []
         for j in range(s):
             pos_j = positions[:, j]
-            valid_j = (idx <= pos_j[:, None]) | (pos_j[:, None] >= sc)
+            valid_j = ops.ring_valid(idx, pos_j, sc, window)
             with tracing.scope("attention_kernel"):
                 outs.append(ops.decode_attention(q[:, j], kc, vc, valid_j))
         out = jnp.stack(outs, axis=1)                                   # [B,S,H,hd]
@@ -278,7 +314,7 @@ def apply_self_attn(
             vc = constrain(vc, "kv_batch", "kv_seq", None, None)
         pos = positions[:, 0]
         idx = jnp.arange(sc)[None, :]
-        valid = (idx <= pos[:, None]) | (pos[:, None] >= sc)            # ring
+        valid = ops.ring_valid(idx, pos, sc, window)                    # ring
         with tracing.scope("attention_kernel"):
             out = ops.decode_attention(q[:, 0], kc, vc, valid)[:, None]  # [B,1,H,hd]
         new_cache = {"k": kc, "v": vc}
@@ -370,12 +406,13 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     f = m.expert_d_ff or cfg.d_ff
     dt = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 5)
+    e = m.num_held                  # the router still scores every expert
     p = {
         "ln": jnp.ones((d,), dt),
         "router": _dense_init(keys[0], (d, m.num_experts), jnp.float32),
-        "we_gate": _dense_init(keys[1], (m.num_experts, d, f), dt, fan_in=d),
-        "we_up": _dense_init(keys[2], (m.num_experts, d, f), dt, fan_in=d),
-        "we_down": _dense_init(keys[3], (m.num_experts, f, d), dt, fan_in=f),
+        "we_gate": _dense_init(keys[1], (e, d, f), dt, fan_in=d),
+        "we_up": _dense_init(keys[2], (e, d, f), dt, fan_in=d),
+        "we_down": _dense_init(keys[3], (e, f, d), dt, fan_in=f),
     }
     if m.num_shared_experts:
         p["shared"] = init_mlp(keys[4], d, f * m.num_shared_experts, dt)
@@ -441,6 +478,48 @@ def apply_moe(p: Params, x: jax.Array, cfg: ModelConfig,
     if "shared" in p:
         y = y + apply_mlp(p["shared"], flat)
     return x + y.reshape(b, s, d), aux
+
+
+def apply_moe_held(p: Params, x: jax.Array, cfg: ModelConfig,
+                   seq_valid: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One chip's share of an expert layer: x [B, S, D] -> (x + the part
+    of the layer's output that the held experts ``cfg.moe.held`` give,
+    [2] int32: token-expert pairs they computed, held experts that computed
+    any).
+
+    Routing is over every expert, in float32: softmax, top-k, the k gates
+    divided by their sum.  The pairs whose expert is held are sorted by
+    expert and run as one grouped matmul (``ops.expert_gmm``) that computes
+    those rows alone: no capacity, no drop, nothing for the experts held
+    elsewhere.  A token whose experts are all held elsewhere gets zero, and
+    right-padding tokens (``seq_valid`` False) route nowhere."""
+    m = cfg.moe
+    b, s, d = x.shape
+    t, k = b * s, m.experts_per_token
+    lo, hi = m.held
+    with tracing.scope("moe"):
+        h = rmsnorm(x, p["ln"], cfg.rms_eps)
+        flat = h.reshape(t, d)
+        logits = jnp.dot(flat.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)          # [T,E]
+        gates, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        gates = gates / gates.sum(-1, keepdims=True)
+        expert = expert.reshape(t * k)
+        held = (expert >= lo) & (expert < hi)
+        if seq_valid is not None:
+            held &= jnp.repeat(seq_valid.reshape(t), k)
+        group = jnp.where(held, expert - lo, hi - lo)    # elsewhere: last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=hi - lo + 1)[:-1].astype(jnp.int32)
+        out = ops.expert_gmm(flat[order // k], p["we_gate"], p["we_up"],
+                             p["we_down"], sizes)       # [T*k, D], sorted
+        out = out[jnp.argsort(order)].reshape(t, k, d)  # pair order again
+        out = jnp.where(held.reshape(t, k, 1), out.astype(jnp.float32), 0.0)
+        y = jnp.sum(out * gates[..., None], axis=1)     # float32, no MXU
+        counts = jnp.stack([held.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+        return x + y.astype(x.dtype).reshape(b, s, d), counts
 
 
 # --------------------------------------------------------------------------- #
@@ -570,6 +649,7 @@ def init_layer(key, kind: str, cfg: ModelConfig, *, d_ff: Optional[int] = None,
     keys = jax.random.split(key, 4)
     dt = jnp.dtype(cfg.dtype)
     p: Params = {}
+    kind = split_kind(kind)[0]
     if kind in ("attn", "moe"):
         p["attn"] = init_attn(keys[0], cfg)
         if has_cross:                          # audio decoder: +cross to encoder
@@ -606,19 +686,28 @@ def apply_layer(
     page_table: Optional[jax.Array] = None,
     slot_active: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x, new_cache, aux_loss).  ``layer`` indexes stacked paged
-    arena leaves (see :func:`apply_self_attn`)."""
+) -> Tuple[jax.Array, Optional[Params], jax.Array, Optional[jax.Array]]:
+    """Returns (x, new_cache, aux_loss, moe_counts).  ``layer`` indexes
+    stacked paged arena leaves (see :func:`apply_self_attn`).  A kind with
+    a window of its own (``"moe@1024"``) attends by it, with the plain
+    rotation; the others attend by ``window`` and take ``cfg.rope_scaling``.
+    ``moe_counts``: what a layer of held experts computed (see
+    :func:`apply_moe_held`; None for every other layer)."""
+    kind, own_window = split_kind(kind)
     aux = jnp.zeros((), jnp.float32)
+    moe_counts = None
     new_cache: Params = {}
     if "attn" in p:
         sub = ({k: cache[k] for k in ARENA_KEYS if k in cache}
                if cache else None)
         x, c = apply_self_attn(p["attn"], x, cfg=cfg, mode=mode,
-                               positions=positions, cache=sub, window=window,
+                               positions=positions, cache=sub,
+                               window=own_window or window,
                                attn_schedule=attn_schedule, resume=resume,
                                seq_valid=seq_valid, page_table=page_table,
-                               slot_active=slot_active, layer=layer)
+                               slot_active=slot_active, layer=layer,
+                               rope_scaling=(None if own_window
+                                             else cfg.rope_scaling))
         if c:
             new_cache.update(c)
     if "cross" in p and kind != "xattn":    # audio decoder cross-attn
@@ -641,7 +730,9 @@ def apply_layer(
                          resume=resume, seq_valid=seq_valid)
         if c:
             new_cache.update(c)
-    if "moe" in p:
+    if "moe" in p and cfg.moe.held is not None:
+        x, moe_counts = apply_moe_held(p["moe"], x, cfg, seq_valid=seq_valid)
+    elif "moe" in p:
         x, aux = apply_moe(p["moe"], x, cfg, seq_valid=seq_valid)
     elif "ffn" in p:
         h = rmsnorm(x, p["ffn_ln"], cfg.rms_eps)
@@ -649,4 +740,4 @@ def apply_layer(
         if kind == "xattn":
             out = jnp.tanh(p["xgate_ffn"].astype(jnp.float32)).astype(out.dtype) * out
         x = x + out
-    return x, (new_cache or None), aux
+    return x, (new_cache or None), aux, moe_counts

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import tracing
-from repro.configs import ModelConfig
+from repro.configs import ModelConfig, split_kind
 from repro.distributed import constrain
 from repro.models import layers
 from repro.models.layers import Params
@@ -46,7 +46,7 @@ def find_pattern(kinds: Tuple[str, ...]) -> Tuple[Tuple[str, ...], Tuple[str, ..
 
 
 def _layer_dff(cfg: ModelConfig, kind: str) -> Optional[int]:
-    if cfg.moe and kind == "attn" and cfg.moe.dense_d_ff:
+    if cfg.moe and split_kind(kind)[0] == "attn" and cfg.moe.dense_d_ff:
         return cfg.moe.dense_d_ff
     return None
 
@@ -55,6 +55,10 @@ class ModelOutput(NamedTuple):
     logits: jax.Array
     cache: Optional[Params]
     aux_loss: jax.Array
+    # [2] int32 over the layers: token-expert pairs the held experts
+    # computed, and held experts that computed any (models whose expert
+    # layers hold a share of the experts; else None)
+    moe_counts: Optional[jax.Array] = None
 
 
 @dataclass(frozen=True)
@@ -120,7 +124,7 @@ class Model:
         pos = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
 
         def body(x, p):
-            x, _, _ = layers.apply_layer(p, "attn", x, cfg=cfg, mode="train",
+            x, _, _, _ = layers.apply_layer(p, "attn", x, cfg=cfg, mode="train",
                                          positions=pos, cache=None,
                                          attn_schedule=attn_schedule)
             return x, None
@@ -185,10 +189,15 @@ class Model:
                                          unroll_scan)
 
         aux_total = jnp.zeros((), jnp.float32)
+        moe_counts = None
+
+        def add_counts(total, counts):
+            return counts if total is None else total + counts
+
         new_prefix_caches = []
         for i, kind in enumerate(prefix):
             sub = cache["prefix"][i] if cache is not None else None
-            x, c, aux = layers.apply_layer(
+            x, c, aux, counts = layers.apply_layer(
                 params["prefix_layers"][i], kind, x, cfg=cfg, mode=mode,
                 positions=positions, cache=sub, window=window_eff,
                 context=context, attn_schedule=attn_schedule,
@@ -197,11 +206,14 @@ class Model:
                 slot_active=slot_active)
             new_prefix_caches.append(c)
             aux_total += aux
+            if counts is not None:
+                moe_counts = add_counts(moe_counts, counts)
 
         def group_body(carry, xs):
             x, arena = carry
             p_slice, c_slice, layer = xs
             aux_g = jnp.zeros((), jnp.float32)
+            counts_g = None
             c_out: Dict[str, Any] = {}
             arena_out: Dict[str, Any] = {}
             for i, kind in enumerate(pattern):
@@ -209,7 +221,7 @@ class Model:
                 sub = c_slice[pos] if c_slice is not None else None
                 if pos in arena:
                     sub = {**sub, **arena[pos]}
-                x, c, aux = layers.apply_layer(
+                x, c, aux, counts = layers.apply_layer(
                     p_slice[pos], kind, x, cfg=cfg, mode=mode,
                     positions=positions, cache=sub, window=window_eff,
                     context=context, attn_schedule=attn_schedule,
@@ -222,7 +234,9 @@ class Model:
                 if c is not None:
                     c_out[pos] = c
                 aux_g += aux
-            return (x, arena_out), (c_out or None, aux_g)
+                if counts is not None:
+                    counts_g = add_counts(counts_g, counts)
+            return (x, arena_out), (c_out or None, aux_g, counts_g)
 
         body = jax.checkpoint(group_body) if (remat and mode == "train") else group_body
         cache_xs = cache["block"] if cache is not None else None
@@ -245,15 +259,19 @@ class Model:
                 ys.append(y)
             caches_g = [y[0] for y in ys]
             aux_total += sum(y[1] for y in ys)
+            if ys[0][2] is not None:
+                moe_counts = add_counts(moe_counts, sum(y[2] for y in ys))
             with tracing.scope("kv_arena"):
                 new_block_cache = (jax.tree.map(
                     lambda *leaves: jnp.stack(leaves), *caches_g)
                     if caches_g[0] is not None else None)
         elif pattern:
             layer_xs = jnp.arange(repeats, dtype=jnp.int32) if arena else None
-            (x, arena), (new_block_cache, aux_g) = jax.lax.scan(
+            (x, arena), (new_block_cache, aux_g, counts_g) = jax.lax.scan(
                 body, (x, arena), (params["block"], cache_xs, layer_xs))
             aux_total += aux_g.sum()
+            if counts_g is not None:
+                moe_counts = add_counts(moe_counts, counts_g.sum(0))
         else:
             new_block_cache = None
         if arena:
@@ -274,7 +292,7 @@ class Model:
             new_cache = {"prefix": new_prefix_caches, "block": new_block_cache}
         elif mode == "decode":
             new_cache = {"prefix": new_prefix_caches, "block": new_block_cache}
-        return ModelOutput(logits, new_cache, aux_total)
+        return ModelOutput(logits, new_cache, aux_total, moe_counts)
 
 
 def _split_arena(block: Params) -> Tuple[Params, Params]:
@@ -299,6 +317,7 @@ def _layer_cache_shape(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
                        ctx_len: int, dtype) -> Params:
     out: Params = {}
     hkv, hd = cfg.num_kv_heads, cfg.head_dim
+    kind = split_kind(kind)[0]
     if kind in ("attn", "moe"):
         out["k"] = jnp.zeros((batch, cache_len, hkv, hd), dtype)
         out["v"] = jnp.zeros((batch, cache_len, hkv, hd), dtype)

@@ -155,3 +155,55 @@ def test_ssd_decode_step_matches_scan():
     got_y = jnp.stack(ys, axis=1)
     np.testing.assert_allclose(got_y, want_y, atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(state, want_s, atol=1e-4, rtol=1e-3)
+
+
+# --------------------------------------------------------------------------- #
+# grouped expert MLP
+# --------------------------------------------------------------------------- #
+def _experts(key, e, d, f, dtype):
+    k1, k2, k3 = jax.random.split(key, 3)
+    return (jax.random.normal(k1, (e, d, f), dtype) / d ** 0.5,
+            jax.random.normal(k2, (e, d, f), dtype) / d ** 0.5,
+            jax.random.normal(k3, (e, f, d), dtype) / f ** 0.5)
+
+
+@pytest.mark.parametrize("sizes", [
+    (37, 0, 150, 3, 0, 61),          # uneven, empty experts, groups
+    (0, 0, 0, 0, 0, 0),              #   straddling 128-row tiles
+    (300, 0, 0, 0, 0, 0),            # one expert takes every row
+    (0, 0, 0, 0, 0, 1),              # a single row, last expert
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_expert_gmm_pallas_vs_dense(sizes, dtype):
+    """Rows sorted by expert, then padding rows that no expert takes: the
+    kernel (and the CPU path) give each grouped row its own expert's MLP,
+    against a dense einsum of every expert on every row."""
+    from repro.kernels.expert_gmm import expert_gmm_pallas
+    m, d, f = 320, 256, 256
+    key = jax.random.PRNGKey(sum(sizes))
+    x = jax.random.normal(key, (m, d), dtype)
+    w = _experts(jax.random.fold_in(key, 1), len(sizes), d, f, dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = ref.expert_gmm_ref(x, *w, gs)
+    rows = sum(sizes)                          # rows past them: undefined
+    tol = ATOL[dtype] * (1 if dtype == jnp.float32 else 2)
+    for got in (expert_gmm_pallas(x, *w, gs, interpret=True),
+                ops.expert_gmm(x, *w, gs)):
+        assert got.shape == (m, d) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got[:rows], np.float32),
+                                   np.asarray(want[:rows]), atol=tol,
+                                   rtol=tol)
+    assert not np.asarray(want[rows:]).any()
+
+
+def test_expert_gmm_visits_each_grouped_tile_once():
+    """The visit list: one (expert, tile) per tile a group touches, in
+    order, then repeats of the last real visit."""
+    from repro.kernels.expert_gmm import visits
+    expert, tile, start, end, count = visits(
+        jnp.asarray([37, 0, 150, 3], jnp.int32), 3, 128)
+    assert int(count[0]) == 4          # e0: t0; e2: t0 and t1; e3: t1
+    assert expert.tolist() == [0, 2, 2, 3, 3, 3]
+    assert tile.tolist() == [0, 0, 1, 1, 1, 1]
+    assert start.tolist() == [0, 37, 37, 187]
+    assert end.tolist() == [37, 37, 187, 190]

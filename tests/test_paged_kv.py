@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.core.engine import InferenceEngine
 from repro.core.paged_kv import PageAllocator, PagedKVPool, PagePoolExhausted
 from repro.core.request import FinishReason, Request, SamplingParams
+from repro.kernels import ops
 from repro.kernels.ref import decode_attention_ref, paged_attention_ref
 from repro.models import build_model
 from repro.serving.tokenizer import ByteTokenizer
@@ -443,6 +444,49 @@ def test_paged_attention_kernel_matches_reference(rng, int8):
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window, pos", [
+    (12, [29, 5, 53, 40]),    # starts inside a page; shorter than the
+                              # window; a wrapped ring; starts mid-page
+    (16, [31, 15, 16, 47]),   # starts exactly on a page boundary
+])
+def test_windowed_paged_attention_kernel(rng, window, pos):
+    """A windowed layer attends the positions after ``pos - window``.  The
+    pages wholly before the window hold NaN: loading them and folding them
+    in would poison the output, so matching the reference shows the kernel
+    passed them by (the ring-wrapped slot reads every page)."""
+    from repro.kernels.paged_attention import first_page, paged_attention_pallas
+
+    b, hq, hkv, d, ps, per_slot = 4, 4, 2, 32, 8, 6
+    total = ps * per_slot
+    q = rng.normal(size=(b, hq, d)).astype(np.float32)
+    kp = rng.normal(size=(b * per_slot + 1, ps, hkv, d)).astype(np.float32)
+    vp = rng.normal(size=kp.shape).astype(np.float32)
+    pt = 1 + np.arange(b * per_slot, dtype=np.int32).reshape(b, per_slot)
+    pos = np.asarray(pos, np.int32)
+    ref = paged_attention_ref(q, kp, vp, pt, pos, window=window)
+    skipped = 0
+    for i, p in enumerate(pos):
+        first = int(first_page(p, window, ps, total))
+        assert first == (0 if p >= total else max(0, p - window + 1) // ps)
+        kp[pt[i, :first]] = vp[pt[i, :first]] = np.nan
+        skipped += first
+    assert skipped
+    out = paged_attention_pallas(q, kp, vp, pt, pos, window=window,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    # the CPU path reads the same cells
+    np.testing.assert_allclose(
+        np.asarray(ops.paged_attention(q, np.nan_to_num(kp),
+                                       np.nan_to_num(vp), pt, pos,
+                                       window=window)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
+    # and the window is a real cut: full attention differs
+    full = paged_attention_ref(q, np.nan_to_num(kp), np.nan_to_num(vp), pt,
+                               pos)
+    assert np.abs(np.asarray(full) - np.asarray(ref)).max() > 1e-2
 
 
 def test_paged_reference_matches_dense_at_full_page(rng):

@@ -4,9 +4,11 @@ TPU compiler for a described (not attached) v5e chip at real model widths.
 Interpret mode checks what a kernel computes, not whether Mosaic accepts
 its block shapes, VMEM use or in-kernel ops; this file checks the latter
 with no chip.  Widths: qwen2-0.5b (H=14, Hkv=2, head_dim 64, bf16, cache
-512, page 16) and one head_dim-128 shape (H=16, Hkv=8).  One whole program,
-the paged ``decode_block`` at Qwen2-7B attention widths, is compiled too, to
-pin what the TPU compiler does with the stacked page arena.
+512, page 16) and one head_dim-128 shape (H=16, Hkv=8); the windowed
+paged kernel and the grouped expert kernel at Mellum 2 widths.  Two whole
+programs are compiled too: the paged ``decode_block`` at Qwen2-7B attention
+widths, to pin what the TPU compiler does with the stacked page arena, and
+Mellum 2's at its published widths, depth and arena, to pin that it fits.
 
 The topology is described inside a module fixture (never at import): only
 one process may load the TPU library at a time, and every test worker
@@ -20,10 +22,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import ModelConfig
+from repro.configs import ModelConfig, MoEConfig, RopeScaling
 from repro.core.engine import InferenceEngine
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.expert_gmm import expert_gmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas
@@ -106,6 +109,30 @@ def test_paged_attention_compiles(one_chip, width, int8):
         _compile(paged_attention_pallas, q, arena, arena, table, pos)
 
 
+@pytest.mark.parametrize("window", [1024, 1000])
+def test_windowed_paged_attention_compiles(one_chip, window):
+    """Mellum 2's windowed layers: 32 / 4 heads of 128, pages of 128, a
+    window on a page boundary and one inside a page."""
+    b, pages = 32, 24
+    q = _sds(one_chip, (b, 32, 128))
+    arena = _sds(one_chip, (b * pages + 2, 128, 4, 128))
+    table = _sds(one_chip, (b, pages), jnp.int32)
+    pos = _sds(one_chip, (b,), jnp.int32)
+    _compile(lambda q, k, v, t, p: paged_attention_pallas(
+        q, k, v, t, p, window=window), q, arena, arena, table, pos)
+
+
+@pytest.mark.parametrize("rows", [256, 4096])
+def test_expert_gmm_compiles(one_chip, rows):
+    """16 held experts of Mellum 2 (d 2,304, width 896): a decode step's
+    32 x 8 token-expert pairs and a prefill wave's."""
+    x = _sds(one_chip, (rows, 2304))
+    wi = _sds(one_chip, (16, 2304, 896))
+    wd = _sds(one_chip, (16, 896, 2304))
+    sizes = _sds(one_chip, (16,), jnp.int32)
+    _compile(expert_gmm_pallas, x, wi, wi, wd, sizes)
+
+
 def test_quant_matmul_compiles(one_chip):
     x = _sds(one_chip, (BATCH, 896))
     w = _sds(one_chip, (896, 4864), jnp.int8)
@@ -184,3 +211,47 @@ def test_paged_decode_block_writes_the_arena_in_place(one_chip, monkeypatch):
     assert slices == len(arena)                  # one read per k / v leaf
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps < sum(a.nbytes for a in arena.values()), temps
+
+
+MELLUM = ModelConfig(
+    name="mellum2-12b-a2.5b", family="moe", num_layers=28, d_model=2304,
+    num_heads=32, num_kv_heads=4, head_dim=128, d_ff=7168,
+    vocab_size=98_304, rope_theta=500_000.0, rms_eps=1e-6,
+    layer_windows=(1024, 1024, 1024, 0),
+    rope_scaling=RopeScaling(factor=16.0,
+                             original_max_position_embeddings=8192,
+                             beta_fast=32.0, beta_slow=1.0,
+                             attention_factor=1.2772588722239782),
+    moe=MoEConfig(num_experts=64, experts_per_token=8, expert_d_ff=896,
+                  held=(0, 16)),
+    dtype="bfloat16")
+
+
+def test_mellum_decode_block_fits_one_chip(one_chip, monkeypatch):
+    """Mellum 2's paged ``decode_block`` (K = 8) at published widths and
+    depth, 16 held experts of 64, 32 slots over 514 pages of 128: both
+    kernels are in it, and weights plus arena (11.43 GB of arguments) and
+    temporaries (0.82 GB) leave a v5e room for a prefill wave; chipbench's
+    configuration file records both.  Built like the engine at full size
+    but without one, so nothing is allocated on the CPU."""
+    import types
+    from repro.core.kv_cache import init_decode_state
+    from repro.core.paged_kv import PagedKVPool
+    params = jax.eval_shape(build_model(MELLUM).init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: PagedKVPool(
+        MELLUM, 32, 3072, page_size=128, num_pages=514).cache)
+    state = jax.eval_shape(lambda: init_decode_state(32, 0, 8,
+                                                     moe_counts=True))
+    engine = types.SimpleNamespace(model=build_model(MELLUM),
+                                   media_kind="none", max_top_logprobs=5,
+                                   _paged=True)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    args = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                        (params, cache, state))
+    compiled = InferenceEngine._build_decode_block_fn(engine).lower(
+        *args, num_steps=8, want_logprobs=False).compile()
+    text = compiled.as_text()
+    assert "expert_gmm" in text and "paged_attention" in text
+    mem = compiled.memory_analysis()
+    assert 11.0e9 < mem.argument_size_in_bytes < 11.6e9
+    assert mem.temp_size_in_bytes < 1.2e9

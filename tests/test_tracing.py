@@ -203,6 +203,40 @@ def test_stats_endpoint_carries_the_counters(cfg):
     assert st["first_tokens"] == 2 and st["kv_grid_pages"] > 0
 
 
+def test_moe_rows_counts_every_held_pair_at_the_block_sync():
+    """``moe_rows``: token-expert pairs the held experts computed in decode,
+    and ``moe_experts``: held experts that computed any, read back with each
+    block's tokens.  With every expert held each of the ``max_batch`` rows
+    of every layer and step is one of them, k times; with half held, fewer.
+    A layer's step visits at least k and at most the held experts.  The
+    host syncs once per block as before, and ``/stats`` serves the
+    counters."""
+    import dataclasses
+    from repro.serving.api import OpenAIServer
+    base = get_config("qwen3-30b-a3b-toy").reduced()
+    e, k = base.moe.num_experts, base.moe.experts_per_token
+    counts = {}
+    for held in ((0, e), (0, e // 2)):
+        cfg = dataclasses.replace(base, moe=dataclasses.replace(
+            base.moe, held=held))
+        eng = _engine(cfg)
+        reqs = _reqs(3)
+        eng.generate(reqs)
+        st = eng.scheduler.stats
+        assert st.steps == eng._decode_blocks     # one sync per block
+        counts[held] = (st.moe_rows, st.moe_experts, st.device_steps)
+        served = OpenAIServer(eng, "toy").stats()
+        assert served["moe_rows"] == st.moe_rows
+        assert served["moe_experts"] == st.moe_experts
+    rows, experts, steps = counts[(0, e)]
+    calls = steps * base.num_layers
+    assert rows == calls * 4 * k                       # max_batch 4
+    assert calls * k <= experts <= calls * e
+    half, experts_half, steps_half = counts[(0, e // 2)]
+    assert 0 < half < steps_half * base.num_layers * 4 * k
+    assert 0 < experts_half <= steps_half * base.num_layers * e // 2
+
+
 def _programs(cfg):
     """Optimised HLO of a decode block and of a prefill wave, printed with
     canonical names and no metadata, and the metadata-carrying text."""
