@@ -148,20 +148,30 @@ def check_kernels(cfg, seed: int) -> None:
     compare("decode_attention", decode_attention_pallas(q, kc, vc, valid),
             lambda: ref.decode_attention_ref(q, kc, vc, valid))
 
+    # a two-layer stacked arena, read at layer 1 in place
     pages = s // ps
     n = b * pages + 1
-    kp, vp = normal((n, ps, hkv, d)), normal((n, ps, hkv, d))
+    kp, vp = normal((2, n, ps, hkv, d)), normal((2, n, ps, hkv, d))
     table = jax.random.permutation(next(keys), n)[:b * pages].reshape(
         b, pages).astype(jnp.int32)
     pos = jax.random.randint(next(keys), (b,), 0, s).astype(jnp.int32)
-    compare("paged_attention", paged_attention_pallas(q, kp, vp, table, pos),
-            lambda: ref.paged_attention_ref(q, kp, vp, table, pos))
+    layer = jnp.array([1], jnp.int32)
+
+    def lanes(x):                  # [.., Hkv, D] -> the arena's [.., Hkv * D]
+        return x.reshape(*x.shape[:-2], -1)
+
+    compare("paged_attention",
+            paged_attention_pallas(q, lanes(kp), lanes(vp), table, pos,
+                                   layer),
+            lambda: ref.paged_attention_ref(q, lanes(kp[1]), lanes(vp[1]),
+                                            table, pos))
     (kq, ks), (vq, vs) = quantize_kv_int8(kp), quantize_kv_int8(vp)
     compare("paged_attention[int8]",
-            paged_attention_pallas(q, kq, vq, table, pos, k_scale=ks,
-                                   v_scale=vs),
-            lambda: ref.paged_attention_ref(q, kq, vq, table, pos,
-                                            k_scale=ks, v_scale=vs))
+            paged_attention_pallas(q, lanes(kq), lanes(vq), table, pos,
+                                   layer, k_scale=ks, v_scale=vs),
+            lambda: ref.paged_attention_ref(q, lanes(kq[1]), lanes(vq[1]),
+                                            table, pos, k_scale=ks[1],
+                                            v_scale=vs[1]))
 
     x = normal((b, cfg.d_model))
     wq, sc = quantize_int8(normal((cfg.d_model, cfg.d_ff), jnp.float32))

@@ -3,7 +3,9 @@
 Replaces the dense per-slot ring of :class:`~repro.core.kv_cache.SlotKVPool`
 for the decode batch (DESIGN_paged_kv.md).  KV memory becomes one arena of
 ``num_pages`` fixed-size pages per attention layer — ``k``/``v`` leaves are
-``[N, page_size, Hkv, hd]`` (stacked block layers ``[L, N, ...]``) — and each
+lane-dense ``[N, page_size, Hkv * hd]``, every KV head of a cell side by
+side, the layout ``paged_attention`` reads (stacked block layers
+``[L, N, ...]``, read in place by layer index) — and each
 slot owns an ordered list of page ids mirrored into a device-resident page
 table ``[max_batch, pages_per_slot]`` that the compiled decode block threads
 through attention (:func:`repro.kernels.ops.paged_attention`).  Non-KV leaves
@@ -22,7 +24,9 @@ pages the block will write are exclusively owned by then.
 The prefill pipeline stays dense (batch=1 rows, unchanged bit-for-bit);
 pagination happens at the commit boundary (:meth:`insert_many` scatters the
 final dense row into the slot's freshly-allocated pages, skipping shared
-ones) and at publication (:meth:`read` gathers pages back to a dense row).
+ones) and at publication (:meth:`read` gathers pages back to a dense row):
+a dense ``[.., S, Hkv, hd]`` block becomes ``[.., S / ps, ps, Hkv * hd]``
+pages on the way in and goes back on the way out.
 
 Bit-exactness: with ``page_size == cache_len`` and fp KV, every page table
 is the identity mapping ``slot -> reserved + slot`` and the arena *is* the
@@ -156,9 +160,18 @@ def _copy_pages_jit(cache, src: jax.Array, dst: jax.Array):
                       lambda a: a.at[:, dst].set(a[:, src]))
 
 
-def _quant_pages(rows: jax.Array):
-    """rows [n, ps, Hkv, hd] fp -> (int8 rows, f32 scales [n, ps, Hkv])."""
-    return quantize_kv_int8(rows)
+def _lanes(x: jax.Array) -> jax.Array:
+    """[.., Hkv, hd] -> the arena's lane-dense [.., Hkv * hd] (a reshape)."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _to_pages(rows: jax.Array, dtype, int8: bool):
+    """Dense pages [.., ps, Hkv, hd] as an arena of ``dtype`` stores them:
+    (lane-dense rows, f32 scales [.., ps, Hkv] or None)."""
+    if int8:
+        q, s = quantize_kv_int8(rows)
+        return _lanes(q), s
+    return _lanes(rows).astype(dtype), None
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("int8",))
@@ -175,24 +188,20 @@ def _paged_insert_jit(cache, singles, slots: jax.Array, page_ids: jax.Array,
     k = len(singles)
     flat_ids = page_ids.reshape(-1)                        # [k*P]
 
-    def paged_prefix(full, *ones):
+    def paged_prefix(full, *ones):                         # [N, ps, lanes]
         ps = full.shape[1]
         rows = jnp.concatenate(
             [o.reshape(-1, ps, *o.shape[2:]) for o in ones], axis=0)
-        if int8:
-            q, s = _quant_pages(rows)
-            return full.at[flat_ids].set(q), s
-        return full.at[flat_ids].set(rows.astype(full.dtype)), None
+        pages, s = _to_pages(rows, full.dtype, int8)
+        return full.at[flat_ids].set(pages), s
 
-    def paged_block(full, *ones):                          # [L, N, ps, ...]
+    def paged_block(full, *ones):                          # [L, N, ps, lanes]
         ps = full.shape[2]
         rows = jnp.concatenate(
             [o.reshape(o.shape[0], -1, ps, *o.shape[3:]) for o in ones],
             axis=1)
-        if int8:
-            q, s = _quant_pages(rows)
-            return full.at[:, flat_ids].set(q), s
-        return full.at[:, flat_ids].set(rows.astype(full.dtype)), None
+        pages, s = _to_pages(rows, full.dtype, int8)
+        return full.at[:, flat_ids].set(pages), s
 
     def dense_prefix(full, *ones):
         many = jnp.concatenate([o.astype(full.dtype) for o in ones], axis=0)
@@ -242,27 +251,28 @@ def _paged_insert_jit(cache, singles, slots: jax.Array, page_ids: jax.Array,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("slot", "int8"))
+@functools.partial(jax.jit, static_argnames=("slot", "int8", "kv_heads"))
 def _gather_slot_jit(cache, page_ids: jax.Array, page_valid: jax.Array, *,
-                     slot: int, int8: bool):
+                     slot: int, int8: bool, kv_heads: int):
     """Gather one slot's pages back into a dense batch=1 cache row.
 
     Never-allocated table entries are masked to zeros so the row is
     bit-identical to what a dense pool would hold (dense rows start from
-    zeros); int8 pages are dequantised back to the dense fp dtype."""
-    scales: Dict[int, Dict[str, jax.Array]] = {}
+    zeros); int8 pages are dequantised back to the dense fp dtype, and the
+    lane-dense cells split back into ``kv_heads`` heads."""
 
     def gather(kv, sc, stacked):
-        ps = kv.shape[2] if stacked else kv.shape[1]
         mask = page_valid[:, None]                        # [P, 1]
         if stacked:
-            rows = kv[:, page_ids]                        # [L, P, ps, ...]
+            rows = kv[:, page_ids]                        # [L, P, ps, lanes]
+            rows = rows.reshape(*rows.shape[:-1], kv_heads, -1)
             m = mask[None, ..., None, None]
             if sc is not None:
                 rows = rows.astype(jnp.float32) * sc[:, page_ids][..., None]
             rows = jnp.where(m, rows, 0)
             return rows.reshape(rows.shape[0], 1, -1, *rows.shape[3:])
-        rows = kv[page_ids]                               # [P, ps, ...]
+        rows = kv[page_ids]                               # [P, ps, lanes]
+        rows = rows.reshape(*rows.shape[:-1], kv_heads, -1)
         if sc is not None:
             rows = rows.astype(jnp.float32) * sc[page_ids][..., None]
         rows = jnp.where(mask[..., None, None], rows, 0)
@@ -416,14 +426,13 @@ class PagedKVPool:
             out = {}
             for name, leaf in sub.items():
                 if name in ("k", "v"):
-                    if stacked:                           # [L, B, S, Hkv, hd]
-                        shape = (leaf.shape[0], n, ps) + leaf.shape[3:]
-                    else:                                 # [B, S, Hkv, hd]
-                        shape = (n, ps) + leaf.shape[2:]
+                    # dense [L?, B, S, Hkv, hd] -> pages [L?, N, ps, Hkv * hd]
+                    pages = (leaf.shape[:1] if stacked else ()) + (n, ps)
+                    hkv, hd = leaf.shape[-2:]
                     dt = jnp.int8 if int8 else leaf.dtype
-                    out[name] = jnp.zeros(shape, dt)
+                    out[name] = jnp.zeros(pages + (hkv * hd,), dt)
                     if int8:
-                        out[name + "_scale"] = jnp.ones(shape[:-1],
+                        out[name + "_scale"] = jnp.ones(pages + (hkv,),
                                                         jnp.float32)
                 else:
                     out[name] = jnp.zeros(leaf.shape, leaf.dtype)
@@ -590,7 +599,8 @@ class PagedKVPool:
         valid[:len(pages)] = True
         return _gather_slot_jit(self.cache, jnp.asarray(ids),
                                 jnp.asarray(valid), slot=slot,
-                                int8=self.kv_dtype == "int8")
+                                int8=self.kv_dtype == "int8",
+                                kv_heads=self.cfg.num_kv_heads)
 
     def read_nonkv(self, slot: int):
         return _read_nonkv(self.cache, slot)

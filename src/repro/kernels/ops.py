@@ -183,35 +183,39 @@ def _decode_jnp(q, k_cache, v_cache, kv_valid):
 
 def paged_attention(
     q: jax.Array,                   # [B, H, D]
-    k_pages: jax.Array,             # [N, ps, Hkv, D] page arena
-    v_pages: jax.Array,             # [N, ps, Hkv, D]
+    k_pages: jax.Array,             # [L, N, ps, Hkv*D] stacked page arena,
+                                    # or one layer's [N, ps, Hkv*D]
+    v_pages: jax.Array,             # like k_pages
     page_table: jax.Array,          # [B, P] int32
     positions: jax.Array,           # [B] int32 query-token positions
+    layer: Optional[jax.Array] = None,   # a stacked arena's layer to read
     *,
-    k_scale: Optional[jax.Array] = None,   # [N, ps, Hkv] f32 (int8 arena)
+    k_scale: Optional[jax.Array] = None,   # [L, N, ps, Hkv] f32 (int8 arena)
     v_scale: Optional[jax.Array] = None,
     window: int = 0,                # > 0: attend the last ``window`` positions
 ) -> jax.Array:
     """Decode attention through a page table (see kernels/paged_attention).
 
-    The CPU path gathers pages into the contiguous [B, S, Hkv, D] layout and
-    reuses ``_decode_jnp`` — at ``page_size == cache_len`` (fp) the gather is
-    an identity extraction, so the math is bit-identical to the dense pool.
-    On TPU the gather never materialises: the Pallas kernel rides the page
-    indirection on its BlockSpec index map."""
+    The CPU path gathers layer ``layer``'s pages into the contiguous
+    [B, S, Hkv, D] layout and reuses ``_decode_jnp`` — at ``page_size ==
+    cache_len`` (fp) the gather is an identity extraction, so the math is
+    bit-identical to the dense pool.  On TPU the gather never materialises:
+    the Pallas kernel rides the layer and the page indirection on its
+    BlockSpec index map."""
     if _on_tpu():
         return paged_attention_pallas(q, k_pages, v_pages, page_table,
-                                      positions, k_scale=k_scale,
+                                      positions, layer, k_scale=k_scale,
                                       v_scale=v_scale, window=window)
-    b = q.shape[0]
-    p, ps = page_table.shape[1], k_pages.shape[1]
+    b, _, d = q.shape
+    p, ps, lanes = page_table.shape[1], *k_pages.shape[-2:]
     s = p * ps
+    at = (page_table,) if layer is None else (layer, page_table)
 
     def gather(pages, scale):
-        rows = pages[page_table]                     # [B, P, ps, Hkv, D]
+        rows = pages[at].reshape(b, p, ps, lanes // d, d)
         if scale is not None:
-            rows = rows.astype(jnp.float32) * scale[page_table][..., None]
-        return rows.reshape(b, s, *pages.shape[2:])
+            rows = rows.astype(jnp.float32) * scale[at][..., None]
+        return rows.reshape(b, s, lanes // d, d)
 
     idx = jnp.arange(s, dtype=jnp.int32)[None, :]
     return _decode_jnp(q, gather(k_pages, k_scale), gather(v_pages, v_scale),

@@ -126,8 +126,8 @@ def ssd_ref(
 
 def paged_attention_ref(
     q: jax.Array,                   # [B, H, D] one new token per sequence
-    k_pages: jax.Array,             # [N, ps, Hkv, D] global page arena
-    v_pages: jax.Array,             # [N, ps, Hkv, D]
+    k_pages: jax.Array,             # [N, ps, Hkv*D] global page arena
+    v_pages: jax.Array,             # [N, ps, Hkv*D]
     page_table: jax.Array,          # [B, P] int32 page id per table entry
     positions: jax.Array,           # [B] int32 position of the query token
     *,
@@ -136,21 +136,22 @@ def paged_attention_ref(
     window: int = 0,                # > 0: only the last ``window`` positions
 ) -> jax.Array:
     """Decode attention through a page table: gather each sequence's pages
-    into a contiguous [B, P*ps, Hkv, D] view (dequantising int8 pages with
-    their per-(position, head) scales), mask cells past the query position
-    (ring semantics: a fully wrapped cache attends to everything) or, with a
+    into a contiguous [B, P*ps, Hkv, D] view (the arena holds every KV head
+    of a cell side by side; int8 pages are dequantised with their
+    per-(position, head) scales), mask cells past the query position (ring
+    semantics: a fully wrapped cache attends to everything) or, with a
     window, cells whose position is ``pos - window`` or earlier, and run the
     dense decode oracle."""
-    b = q.shape[0]
+    b, _, d = q.shape
     p = page_table.shape[1]
-    ps = k_pages.shape[1]
+    ps, lanes = k_pages.shape[1:]
     s = p * ps
 
     def gather(pages, scale):
-        rows = pages[page_table]                     # [B, P, ps, Hkv, D]
+        rows = pages[page_table].reshape(b, p, ps, lanes // d, d)
         if scale is not None:
             rows = rows.astype(jnp.float32) * scale[page_table][..., None]
-        return rows.reshape(b, s, *pages.shape[2:])
+        return rows.reshape(b, s, lanes // d, d)
 
     k = gather(k_pages, k_scale)
     v = gather(v_pages, v_scale)

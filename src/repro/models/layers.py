@@ -207,27 +207,24 @@ def apply_self_attn(
         return x + out @ p["wo"], {"k": kc, "v": vc}
 
     if mode == "decode" and page_table is not None:
-        # paged KV: cache holds the global page arena [N, ps, Hkv, hd] and
-        # the slot's cells are reached through page_table.  The engine's
-        # ensure_decode_capacity guarantees the write target is an
-        # exclusively-owned page; frozen slots (slot_active False) redirect
-        # their write to a reserved per-slot trash cell so shared/retired
-        # pages are never touched (the paged analogue of the dense path's
-        # select_cache_slots ring-cell repair).  Inside the layer scan the
-        # arena leaves are the whole stack [L, N, ps, Hkv, hd]: the cells
-        # are written in place at ``layer`` and the kernel is handed that
-        # layer's slice alone.  No sharding constrain on the arena: paged +
-        # distributed KV is not supported.
+        # paged KV: cache holds the global page arena [N, ps, Hkv * hd]
+        # (every KV head of a cell side by side) and the slot's cells are
+        # reached through page_table.  The engine's ensure_decode_capacity
+        # guarantees the write target is an exclusively-owned page; frozen
+        # slots (slot_active False) redirect their write to a reserved
+        # per-slot trash cell so shared/retired pages are never touched (the
+        # paged analogue of the dense path's select_cache_slots ring-cell
+        # repair).  Inside the layer scan the arena leaves are the whole
+        # stack [L, N, ps, Hkv * hd]: the cells are written in place at
+        # ``layer`` and the kernel reads that layer out of the stack itself.
+        # No sharding constrain on the arena: paged + distributed KV is not
+        # supported.
         kc, vc = cache["k"], cache["v"]
         ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
-        ps = kc.shape[-3]
+        ps = kc.shape[-2]
         sc = ps * page_table.shape[1]
         bidx = jnp.arange(b)
         lead = () if layer is None else (layer,)
-
-        def at_layer(a):
-            return a if layer is None or a is None else a[layer]
-
         live = slot_active if slot_active is not None else jnp.ones((b,), bool)
         outs = []
         # S > 1 is speculative verification under paging: per-position
@@ -251,18 +248,17 @@ def apply_self_attn(
                 if ksc is not None:                         # int8 arena
                     kq, ks_j = quantize_kv_int8(k[:, j])
                     vq, vs_j = quantize_kv_int8(v[:, j])
-                    kc = kc.at[at].set(kq)
-                    vc = vc.at[at].set(vq)
+                    kc = kc.at[at].set(kq.reshape(b, -1))
+                    vc = vc.at[at].set(vq.reshape(b, -1))
                     ksc = ksc.at[at].set(ks_j)
                     vsc = vsc.at[at].set(vs_j)
                 else:
-                    kc = kc.at[at].set(k[:, j])
-                    vc = vc.at[at].set(v[:, j])
+                    kc = kc.at[at].set(k[:, j].reshape(b, -1))
+                    vc = vc.at[at].set(v[:, j].reshape(b, -1))
             with tracing.scope("attention_kernel"):
                 outs.append(ops.paged_attention(
-                    q[:, j], at_layer(kc), at_layer(vc), page_table, pos_j,
-                    k_scale=at_layer(ksc), v_scale=at_layer(vsc),
-                    window=window))
+                    q[:, j], kc, vc, page_table, pos_j, layer,
+                    k_scale=ksc, v_scale=vsc, window=window))
         out = jnp.stack(outs, axis=1)                               # [B,S,H,hd]
         new_cache = ({"k": kc, "v": vc} if ksc is None else
                      {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc})

@@ -321,6 +321,65 @@ def test_pool_insert_read_roundtrip_and_occupancy(cfg):
     assert pool.stats.allocs == pool.stats.frees == 3
 
 
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+@pytest.mark.parametrize("layout", ["stack", "prefix"])
+def test_pool_insert_read_adopt_roundtrip_is_bit_exact(layout, kv_dtype):
+    """The arena stores a cell's KV heads side by side: a dense row's page
+    of [ps, Hkv, hd] lands as one [ps, Hkv * hd] arena page, stacked block
+    layers at their layer index.  Inserting a slot, reading it back, and
+    adopting its pages into a second slot (a snapshot's resume) give the
+    same dense KV bit for bit; under int8 the read is the dequantised row
+    and the adopted slot reads back exactly the same."""
+    arch, reduce = LAYOUTS[layout]
+    cfg = get_config(arch)
+    cfg = cfg if reduce is None else cfg.reduced(**reduce)
+    pool = PagedKVPool(cfg, max_batch=2, cache_len=64, page_size=16,
+                       kv_dtype=kv_dtype)
+    single = jax.tree.map(
+        lambda a: jnp.asarray(np.random.default_rng(3).normal(
+            size=a.shape).astype(a.dtype) if jnp.issubdtype(
+                a.dtype, jnp.floating) else np.zeros(a.shape, a.dtype)),
+        pool.single_cache_zeros())
+    slot = pool.allocate()
+    pool.insert_many([slot], [single])
+    pages = pool.slot_pages(slot)
+    hkv, hd = cfg.num_kv_heads, cfg.head_dim
+
+    def kv_rows(cache):            # (arena-or-dense leaf, stacked) per k / v
+        subs = [(sub, False) for sub in cache["prefix"]] + [
+            (sub, True) for sub in (cache["block"] or {}).values()]
+        return [(sub[n], stacked) for sub, stacked in subs
+                for n in ("k", "v") if n in sub]
+
+    arena, dense = kv_rows(pool.cache), kv_rows(single)
+    assert arena and {stacked for _, stacked in arena} == (
+        {True} if layout == "stack" else {False, True})
+    for (leaf, stacked), (row, _) in zip(arena, dense):
+        lead = leaf.shape[:1] if stacked else ()
+        assert leaf.shape == lead + (pool.num_pages, 16, hkv * hd)
+        if kv_dtype == "fp":
+            got = np.asarray(leaf)[:, pages] if stacked else np.asarray(
+                leaf)[pages]
+            want = np.asarray(row[:, 0] if stacked else row[0])
+            np.testing.assert_array_equal(
+                got, want.reshape(*lead, len(pages), 16, hkv * hd))
+
+    back = pool.read(slot)
+    pool.incref_pages(pages)                     # the snapshot's lease
+    other = pool.allocate()
+    pool.adopt(other, pages, pool.read_nonkv(slot))
+    again = pool.read(other)
+    for (a, _), (b, _), (want, _) in zip(kv_rows(back), kv_rows(again),
+                                         dense):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if kv_dtype == "fp":
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(want))
+        else:
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(want, np.float32),
+                                       atol=0.05, rtol=0.05)
+
+
 def test_pool_ensure_capacity_allocates_tail_and_splits_shared(cfg):
     pool = PagedKVPool(cfg, max_batch=2, cache_len=64, page_size=16)
     single = pool.single_cache_zeros()
@@ -422,70 +481,121 @@ def test_paged_decode_scanned_matches_unrolled(layout, kv_dtype):
 # --------------------------------------------------------------------------- #
 # kernel: interpret-mode pallas vs host reference
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("int8", [False, True])
-def test_paged_attention_kernel_matches_reference(rng, int8):
+def _arena(rng, layers, npages, ps, hkv, d, int8):
+    """A stacked lane-dense K/V arena [L, N, ps, Hkv * d] whose layers hold
+    different cells, with its int8 scales [L, N, ps, Hkv] (or None)."""
+    from repro.kernels.quant_matmul import quantize_kv_int8
+    kp, vp = (rng.normal(size=(layers, npages, ps, hkv, d)).astype(np.float32)
+              for _ in range(2))
+    ks = vs = None
+    if int8:
+        (kp, ks), (vp, vs) = quantize_kv_int8(kp), quantize_kv_int8(vp)
+        ks, vs = np.array(ks), np.array(vs)
+    return (np.array(kp).reshape(layers, npages, ps, hkv * d),
+            np.array(vp).reshape(layers, npages, ps, hkv * d), ks, vs)
+
+
+def _layer_operands(arena, layer):
+    """The kernel's operands for one case — ``layer`` None: the unstacked
+    leaf of layer 0; else the whole stack and the layer index — and the
+    reference's (that layer's leaves)."""
+    kp, vp, ks, vs = arena
+    at = 0 if layer is None else layer
+    one = [a if a is None else a[at] for a in (kp, vp, ks, vs)]
+    if layer is None:
+        return one, None, one
+    return [kp, vp, ks, vs], np.array([layer], np.int32), one
+
+
+def _stacked_cases(bases, fp_id, int8_id):
+    """(base..., int8, layer) params: each base case on an unstacked leaf
+    and read out of a 3-layer stack at layers 0 and 2, fp and int8; ids
+    join the base's, the dtype's and the layer's."""
+    return [pytest.param(*base, int8, layer, id="-".join(filter(None, (
+                bid, int8_id if int8 else fp_id,
+                "" if layer is None else f"layer{layer}"))))
+            for base, bid in bases for layer in (None, 0, 2)
+            for int8 in (False, True)]
+
+
+@pytest.mark.parametrize("int8, layer",
+                         _stacked_cases([((), "")], "False", "True"))
+def test_paged_attention_kernel_matches_reference(rng, int8, layer):
+    """The kernel reads one layer of a stacked arena in place (the layer
+    index is a scalar-prefetch operand), or an unstacked leaf as a stack
+    of one: it matches the reference on that layer alone."""
     from repro.kernels.paged_attention import paged_attention_pallas
 
     b, hq, hkv, d, ps, pages_per_slot, npages = 3, 4, 2, 32, 8, 4, 16
     q = rng.normal(size=(b, hq, d)).astype(np.float32)
-    kp = rng.normal(size=(npages, ps, hkv, d)).astype(np.float32)
-    vp = rng.normal(size=(npages, ps, hkv, d)).astype(np.float32)
+    arena = _arena(rng, 3, npages, ps, hkv, d, int8)
     pt = rng.integers(1, npages, size=(b, pages_per_slot)).astype(np.int32)
     pos = np.array([5, 17, 31], np.int32)
-    ks = vs = None
-    if int8:
-        from repro.kernels.quant_matmul import quantize_kv_int8
-        kp, ks = quantize_kv_int8(kp)
-        vp, vs = quantize_kv_int8(vp)
-        kp, vp = np.asarray(kp), np.asarray(vp)
-        ks, vs = np.asarray(ks), np.asarray(vs)
-    ref = paged_attention_ref(q, kp, vp, pt, pos, k_scale=ks, v_scale=vs)
-    out = paged_attention_pallas(q, kp, vp, pt, pos, k_scale=ks, v_scale=vs,
-                                 interpret=True)
+    (kp, vp, ks, vs), at, (k1, v1, ks1, vs1) = _layer_operands(arena, layer)
+    ref = paged_attention_ref(q, k1, v1, pt, pos, k_scale=ks1, v_scale=vs1)
+    out = paged_attention_pallas(q, kp, vp, pt, pos, at, k_scale=ks,
+                                 v_scale=vs, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    # the CPU path reads the same layer
+    np.testing.assert_allclose(
+        np.asarray(ops.paged_attention(q, kp, vp, pt, pos, at, k_scale=ks,
+                                       v_scale=vs)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
+    if layer is not None:         # and the layers differ
+        other = paged_attention_ref(
+            q, *(a if a is None else a[1] for a in arena[:2]), pt, pos,
+            **({} if ks is None else {"k_scale": ks[1], "v_scale": vs[1]}))
+        assert np.abs(np.asarray(other) - np.asarray(ref)).max() > 1e-2
 
 
-@pytest.mark.parametrize("window, pos", [
-    (12, [29, 5, 53, 40]),    # starts inside a page; shorter than the
-                              # window; a wrapped ring; starts mid-page
-    (16, [31, 15, 16, 47]),   # starts exactly on a page boundary
-])
-def test_windowed_paged_attention_kernel(rng, window, pos):
+@pytest.mark.parametrize("window, pos, int8, layer", _stacked_cases([
+    ((12, [29, 5, 53, 40]), "12-pos0"),   # starts inside a page; shorter
+                                          # than the window; a wrapped ring;
+                                          # starts mid-page
+    ((16, [31, 15, 16, 47]), "16-pos1"),  # starts exactly on a page boundary
+], "", "int8"))
+def test_windowed_paged_attention_kernel(rng, window, pos, int8, layer):
     """A windowed layer attends the positions after ``pos - window``.  The
-    pages wholly before the window hold NaN: loading them and folding them
-    in would poison the output, so matching the reference shows the kernel
-    passed them by (the ring-wrapped slot reads every page)."""
+    pages wholly before the window hold NaN (an int8 page its scales):
+    loading them and folding them in would poison the output, so matching
+    the reference shows the kernel passed them by (the ring-wrapped slot
+    reads every page)."""
     from repro.kernels.paged_attention import first_page, paged_attention_pallas
 
     b, hq, hkv, d, ps, per_slot = 4, 4, 2, 32, 8, 6
     total = ps * per_slot
     q = rng.normal(size=(b, hq, d)).astype(np.float32)
-    kp = rng.normal(size=(b * per_slot + 1, ps, hkv, d)).astype(np.float32)
-    vp = rng.normal(size=kp.shape).astype(np.float32)
+    arena = _arena(rng, 3, b * per_slot + 1, ps, hkv, d, int8)
     pt = 1 + np.arange(b * per_slot, dtype=np.int32).reshape(b, per_slot)
     pos = np.asarray(pos, np.int32)
-    ref = paged_attention_ref(q, kp, vp, pt, pos, window=window)
+    (kp, vp, ks, vs), at, (k1, v1, ks1, vs1) = _layer_operands(arena, layer)
+    ref = paged_attention_ref(q, k1, v1, pt, pos, k_scale=ks1, v_scale=vs1,
+                              window=window)
     skipped = 0
+    poison = (k1, v1) if ks is None else (ks1, vs1)   # views into the stack
     for i, p in enumerate(pos):
         first = int(first_page(p, window, ps, total))
         assert first == (0 if p >= total else max(0, p - window + 1) // ps)
-        kp[pt[i, :first]] = vp[pt[i, :first]] = np.nan
+        for a in poison:
+            a[pt[i, :first]] = np.nan
         skipped += first
     assert skipped
-    out = paged_attention_pallas(q, kp, vp, pt, pos, window=window,
-                                 interpret=True)
+    out = paged_attention_pallas(q, kp, vp, pt, pos, at, k_scale=ks,
+                                 v_scale=vs, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     # the CPU path reads the same cells
+    clean = [a if a is None else np.nan_to_num(a) for a in (kp, vp, ks, vs)]
     np.testing.assert_allclose(
-        np.asarray(ops.paged_attention(q, np.nan_to_num(kp),
-                                       np.nan_to_num(vp), pt, pos,
+        np.asarray(ops.paged_attention(q, clean[0], clean[1], pt, pos, at,
+                                       k_scale=clean[2], v_scale=clean[3],
                                        window=window)),
         np.asarray(ref), atol=2e-5, rtol=2e-5)
     # and the window is a real cut: full attention differs
-    full = paged_attention_ref(q, np.nan_to_num(kp), np.nan_to_num(vp), pt,
-                               pos)
+    k1, v1, ks1, vs1 = (a if a is None else np.nan_to_num(a)
+                        for a in (k1, v1, ks1, vs1))
+    full = paged_attention_ref(q, k1, v1, pt, pos, k_scale=ks1, v_scale=vs1)
     assert np.abs(np.asarray(full) - np.asarray(ref)).max() > 1e-2
 
 
@@ -500,5 +610,6 @@ def test_paged_reference_matches_dense_at_full_page(rng):
     kv_valid = np.arange(s)[None, :] <= pos[:, None]
     ref = decode_attention_ref(q, k, v, kv_valid)
     pt = np.arange(b, dtype=np.int32)[:, None]           # slot -> page slot
-    out = paged_attention_ref(q, k, v, pt, pos)
+    out = paged_attention_ref(q, k.reshape(b, s, hkv * d),
+                              v.reshape(b, s, hkv * d), pt, pos)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
